@@ -342,6 +342,7 @@ def bench_step_pipeline(out_dir: str = "experiments/dryrun"
     for n_dev in (4, 8):
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = (os.path.join(repo, "src") + os.pathsep
                              + env.get("PYTHONPATH", ""))
         proc = subprocess.run([sys.executable, "-c", _PIPELINE_PROBE],
@@ -503,6 +504,7 @@ def bench_telemetry(out_dir: str = "experiments/dryrun"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (os.path.join(repo, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     proc = subprocess.run([sys.executable, "-c", _TELEMETRY_PROBE],

@@ -96,6 +96,7 @@ def bench_serving(out_dir: str = "experiments/dryrun"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = (os.path.join(repo, "src") + os.pathsep
                          + env.get("PYTHONPATH", ""))
     proc = subprocess.run([sys.executable, "-c", _SERVING_PROBE],

@@ -16,6 +16,7 @@ from repro.core.graph import er_graph, sbm_graph
 from repro.core.execution.spmm_models import (spmm_replicated, spmm_1d_broadcast,
     spmm_1d_ring, spmm_1d_p2p, spmm_2d_summa, spmm_15d, p2p_plan)
 from repro.launch.hlo_analysis import collective_bytes
+from repro.compat import make_mesh
 
 V, D = 512, 64
 g = sbm_graph(V, num_blocks=8, p_in=0.04, p_out=0.002, seed=0)
@@ -27,8 +28,8 @@ order = np.argsort(part.assignment, kind="stable")
 A_np = g.to_dense_adj()[np.ix_(order, order)]
 A = jnp.asarray(A_np)
 H = jnp.asarray(np.random.default_rng(0).standard_normal((V, D)).astype(np.float32))
-m1 = jax.make_mesh((8,), ("w",))
-m2 = jax.make_mesh((4, 2), ("r", "c"))
+m1 = make_mesh((8,), ("w",))
+m2 = make_mesh((4, 2), ("r", "c"))
 rows = []
 def measure(name, fn, mesh, *extra):
     comp = jax.jit(lambda a, h: fn(mesh, a, h, *extra)).lower(A, H).compile()
@@ -50,7 +51,7 @@ def bench_spmm_comm() -> Tuple[List[Dict], str]:
     import json
 
     env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(REPO, "src"))
+               JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(REPO, "src"))
     proc = subprocess.run([sys.executable, "-c", _CODE], capture_output=True,
                           text=True, timeout=600, env=env)
     if proc.returncode != 0:

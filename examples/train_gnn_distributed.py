@@ -67,6 +67,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import make_mesh
+from repro.configs import gcn_paper
 from repro.core.engine import (
     BATCHING_MODES,
     ENGINE_CACHE_POLICIES,
@@ -80,34 +82,39 @@ from repro.core.execution.spmm_models import SPMM_MODELS
 from repro.core.graph import sbm_graph
 from repro.core.models.gnn import accuracy, full_graph_forward, init_gnn_params, softmax_xent
 from repro.core.partition import PARTITIONERS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import collective_bytes, executable_summary
 
 
 def run_engine(args, g):
     fanouts = tuple(int(x) for x in args.fanouts.split(","))
     layer_sizes = tuple(int(x) for x in args.layer_sizes.split(","))
-    cfg = EngineConfig(execution=args.exec, protocol=args.protocol,
-                       model=args.model,
-                       partition_family=args.partition_family,
-                       partitioner=args.partition,
-                       vertex_cut=args.vertex_cut,
-                       hub_threshold=args.hub_threshold, lr=args.lr,
-                       batching=args.batching, batch_size=args.batch_size,
-                       fanouts=fanouts, layer_sizes=layer_sizes,
-                       walk_length=args.walk_length,
-                       cache_policy=args.cache,
-                       cache_capacity=args.cache_capacity,
-                       exchange_chunks=args.exchange_chunks,
-                       p2p_buckets=args.p2p_buckets,
-                       prefetch_depth=args.prefetch_depth,
-                       prefetch_mode=args.prefetch_mode,
-                       num_sample_workers=args.num_sample_workers,
-                       trainable_features=args.trainable_features,
-                       embed_lr=args.embed_lr)
+    fields = dict(execution=args.exec, protocol=args.protocol,
+                  partition_family=args.partition_family,
+                  vertex_cut=args.vertex_cut,
+                  hub_threshold=args.hub_threshold,
+                  batching=args.batching, batch_size=args.batch_size,
+                  fanouts=fanouts, layer_sizes=layer_sizes,
+                  walk_length=args.walk_length,
+                  cache_policy=args.cache,
+                  cache_capacity=args.cache_capacity,
+                  exchange_chunks=args.exchange_chunks,
+                  p2p_buckets=args.p2p_buckets,
+                  prefetch_depth=args.prefetch_depth,
+                  prefetch_mode=args.prefetch_mode,
+                  num_sample_workers=args.num_sample_workers,
+                  trainable_features=args.trainable_features,
+                  embed_lr=args.embed_lr)
+    given = dict(model=args.model, partitioner=args.partition, lr=args.lr)
+    fields.update({k: v for k, v in given.items() if v is not None})
+    if args.config:  # widths, depth, model, partitioner, lr of the config
+        cfg = gcn_paper.engine_config(gcn_paper.CONFIG, **fields)
+    else:
+        cfg = EngineConfig(**fields)
     n_dev = len(jax.devices())
     k = args.parts or n_dev
     assert k <= n_dev, f"need {k} devices, have {n_dev} (set XLA_FLAGS)"
-    mesh = jax.make_mesh((k,), ("w",))
+    mesh = make_mesh((k,), ("w",))
     eng = DistGNNEngine(g, mesh=mesh, cfg=cfg)
     tel = eng.enable_telemetry() if args.trace_out else eng.telemetry
     minibatch = args.batching != "full_graph"
@@ -126,10 +133,10 @@ def run_engine(args, g):
                f"({int(lay.cut.hub.sum())} hubs, "
                f"replication={lay.layout.replication_factor():.2f})")
     else:
-        cut = f"partition={args.partition}"
-    print(f"engine: model={args.model} exec={args.exec} "
+        cut = f"partition={cfg.partitioner}"
+    print(f"engine: model={cfg.model} exec={args.exec} "
           f"protocol={args.protocol} "
-          f"batching={args.batching} {cut} k={k} "
+          f"batching={args.batching} dims={eng.dims} {cut} k={k} "
           f"(nb={eng.nb}, halo cap={getattr(eng, 'cap', '-')}"
           + (f", frontier caps={eng.caps} fcap={eng.fcap}" if minibatch else "")
           + f") collective bytes/step = {coll / 1e6:.2f} MB  {kinds}")
@@ -226,9 +233,9 @@ def run_legacy(args, g):
         r = int(np.sqrt(k))
         while k % r:
             r -= 1
-        mesh = jax.make_mesh((r, k // r), ("r", "c"))
+        mesh = make_mesh((r, k // r), ("r", "c"))
     else:
-        mesh = jax.make_mesh((k,), ("w",))
+        mesh = make_mesh((k,), ("w",))
     spmm = SPMM_MODELS[args.exec]
 
     def aggregate(A_, H_):
@@ -271,8 +278,14 @@ def main():
                     help=f"engine: {EXECUTION_MODELS} (default p2p); "
                     f"legacy: {list(SPMM_MODELS)} (default spmm_1d)")
     ap.add_argument("--protocol", default="sync", choices=list(PROTOCOLS))
-    ap.add_argument("--model", default="gcn", choices=list(GNN_MODELS),
-                    help="engine GNN layer program (§3 model axis): gcn | "
+    ap.add_argument("--config", default=None, choices=["gcn-paper"],
+                    help="engine: take vertices, average degree, feature "
+                    "and hidden widths, classes, layers, model, "
+                    "partitioner and lr from configs/gcn_paper.py (graph "
+                    "from er_graph); --model/--partition/--lr override")
+    ap.add_argument("--model", default=None, choices=list(GNN_MODELS),
+                    help="engine GNN layer program (default gcn; §3 model "
+                    "axis): gcn | "
                     "sage | gat | gin — gat runs distributed edge-wise "
                     "attention (SDDMM logits + masked segment-softmax; "
                     "two-pass replica sync under vertex_cut)")
@@ -326,7 +339,9 @@ def main():
                     help="power-of-two installments splitting the p2p "
                     "all_to_all send caps (smaller lowered buffers)")
     ap.add_argument("--parts", type=int, default=0, help="0 = all devices")
-    ap.add_argument("--partition", default="metis_like")
+    ap.add_argument("--partition", default=None,
+                    help="edge-cut partitioner (default metis_like, or the "
+                    "config's)")
     ap.add_argument("--partition-family", default="edge_cut",
                     choices=["edge_cut", "vertex_cut", "hybrid"],
                     help="engine §4 partition family: edge-cut halo exchange, "
@@ -344,7 +359,8 @@ def main():
                     "pure edge-cut dataflow, 0 -> pure vertex-cut)")
     ap.add_argument("--epochs", type=int, default=40)
     ap.add_argument("--vertices", type=int, default=512)
-    ap.add_argument("--lr", type=float, default=0.5)
+    ap.add_argument("--lr", type=float, default=None,
+                    help="SGD step (default 0.5, or the config's)")
     ap.add_argument("--trace-out", default=None, metavar="t.json",
                     help="engine: enable run-wide telemetry and write a "
                     "Chrome trace-event file here (open in Perfetto / "
@@ -384,7 +400,21 @@ def main():
             ap.error(f"{args.partition_family} supports --batching "
                      "full_graph only (replica-family mini-batch sampling "
                      "is a ROADMAP follow-up)")
-    g = sbm_graph(args.vertices, num_blocks=8, p_in=0.05, p_out=0.003, seed=0)
+    if args.config and not args.engine:
+        ap.error("--config runs on the engine path only")
+    if not args.engine:
+        args.partition = args.partition or "metis_like"
+    enable_compile_cache()
+    if args.config:
+        wl = gcn_paper.CONFIG
+        g = gcn_paper.build_graph(wl)
+        print(f"graph: {gcn_paper.GRAPH_GENERATOR}(V={wl.num_vertices}, "
+              f"avg_degree={wl.avg_degree}) E={g.num_edges} "
+              f"max in-degree={int(g.degree().max())} "
+              f"features={wl.feature_dim} classes={wl.num_classes}")
+    else:
+        g = sbm_graph(args.vertices, num_blocks=8, p_in=0.05, p_out=0.003,
+                      seed=0)
     if args.engine:
         run_engine(args, g)
     else:

@@ -1,137 +1,42 @@
-"""Version-portable jax compatibility shims (shard_map + Pallas drift).
+"""The one place that owns jax API drift for this repo.
 
-Every module in this repo that needs ``shard_map`` must import it from here —
-never from ``jax`` or ``jax.experimental`` directly.  The shim absorbs the two
-API moves that otherwise fork the codebase per jax version:
+Supported version: the installed jax 0.9 (jaxlib 0.9, libtpu 0.0.34).  Code
+for older releases is not kept; when the installed jax moves, this module is
+where the repo follows it.
 
-* **Location**: ``shard_map`` lives at ``jax.experimental.shard_map`` up to
-  ~0.4.x / 0.5.x and is re-exported as ``jax.shard_map`` from jax>=0.6
-  (experimental alias ``jax.shard_map`` already appears in some 0.4.35+
-  builds).  Importing the missing one raises ``ImportError`` /
-  ``AttributeError`` depending on the path — we probe both.
-* **Replication-check kwarg**: the ``check_rep`` kwarg (<=0.5) was renamed
-  ``check_vma`` (>=0.6, varying-manual-axes rework).  Callers here use either
-  spelling; the shim rewrites it to whatever the installed jax accepts.
-
-Supported / tested versions:
-
-* jax 0.4.3x (CI floor; 0.4.37 is the pinned container toolchain):
-  ``jax.experimental.shard_map.shard_map`` with ``check_rep``; Pallas
-  interpret-mode ``pl.load`` requires ``Slice``/array indices (no bare ints —
-  use :func:`pallas_block_slice` / ``pl.dslice`` everywhere).
-* jax >=0.6 (forward-compat path, exercised via the kwarg-rewrite branch):
-  ``jax.shard_map`` with ``check_vma``.
-
-Extending to a new jax release: if ``shard_map``'s signature gains/renames a
-kwarg, add the rename to ``_KWARG_ALIASES`` below; nothing else in the repo
-should need to change.
+* ``shard_map``: ``jax.shard_map`` (its replication check is spelled
+  ``check_vma``).
+* ``make_mesh``: every mesh in the repo is built here, with ``Auto`` axes.
+  ``jax.make_mesh`` defaults to ``Explicit`` axes since 0.7, under which the
+  engine's sharded concatenates, ``vmap`` and ``dynamic_update_slice`` are
+  refused with ``ShardingTypeError``; the repo's programs place data with
+  ``shard_map`` and ``NamedSharding``, which is what ``Auto`` axes mean.
+* ``interpret_default``: Pallas kernels compile on a TPU backend and run in
+  interpret mode everywhere else.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-from typing import Any
+from typing import Sequence
 
 import jax
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-_raw_shard_map = getattr(jax, "shard_map", None)
-if _raw_shard_map is None:  # jax <= 0.5.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _raw_shard_map  # type: ignore
-
-# Either spelling of the replication-check kwarg is accepted by callers; the
-# installed jax accepts exactly one of them.
-_KWARG_ALIASES = [("check_vma", "check_rep")]
+from jax.sharding import AxisType
 
 
-@functools.lru_cache(maxsize=None)
-def _accepted_kwargs() -> frozenset:
-    try:
-        return frozenset(inspect.signature(_raw_shard_map).parameters)
-    except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-        return frozenset()
+shard_map = jax.shard_map
 
 
-def shard_map(f=None, /, **kwargs: Any):
-    """Drop-in ``shard_map`` accepting both ``check_rep`` and ``check_vma``.
-
-    Usage is keyword-style, as everywhere in this repo::
-
-        fn = shard_map(local_fn, mesh=mesh, in_specs=..., out_specs=...,
-                       check_vma=False)
-    """
-    accepted = _accepted_kwargs()
-    for a, b in _KWARG_ALIASES:
-        for src, dst in ((a, b), (b, a)):
-            if src in kwargs and src not in accepted and dst in accepted:
-                kwargs[dst] = kwargs.pop(src)
-        # neither spelling supported: drop it rather than crash (the check is
-        # a debugging aid, not a semantics change)
-        for name in (a, b):
-            if name in kwargs and name not in accepted:
-                kwargs.pop(name)
-    if f is None:
-        return functools.partial(shard_map, **kwargs)
-    return _raw_shard_map(f, **kwargs)
-
-
-# ---------------------------------------------------------------------------
-# Pallas drift
-# ---------------------------------------------------------------------------
-
-
-def pallas_block_slice(start: int, size: int):
-    """``pl.dslice`` indirection point.
-
-    jax 0.4.3x interpret-mode ``pl.load`` discharge requires every index to be
-    a ``Slice`` or an array — a bare python int (``ref[(0, ...)]``-style)
-    crashes with ``'int' object has no attribute 'shape'``.  Kernels index the
-    leading block dim with ``pallas_block_slice(i, 1)`` and squeeze instead.
-    """
-    from jax.experimental import pallas as pl
-
-    return pl.dslice(start, size)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a dict on jax>=0.5 but a
-    one-element *list* of dicts on 0.4.x (one per device-program).  Normalize
-    to a plain dict (empty when unavailable)."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:  # pragma: no cover - backend without cost model
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
-
-
-def peak_memory_in_bytes(memory_stats) -> int:
-    """``CompiledMemoryStats.peak_memory_in_bytes`` only exists on newer jax;
-    0.4.x exposes argument/temp/output sizes.  Fall back to their sum (an
-    upper-ish proxy for the peak) when the field is absent."""
-    peak = getattr(memory_stats, "peak_memory_in_bytes", None)
-    if peak is not None:
-        return int(peak)
-    return int(memory_stats.argument_size_in_bytes
-               + memory_stats.temp_size_in_bytes
-               + memory_stats.output_size_in_bytes)
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types (see the module docstring).
+    ``devices`` defaults to ``jax.devices()``; a described topology's devices
+    may be passed to build a mesh for a chip that is not attached."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def interpret_default() -> bool:
-    """Whether Pallas kernels should run in interpret mode by default: True on
-    anything that is not a real TPU backend (CPU/GPU hosts, forced-host-device
-    test meshes).
-
-    Deliberately includes GPU: the repo's kernels are TPU-styled and their
-    Triton lowering is untested, so interpret mode (which traces to plain XLA
-    ops under jit — correct, just not kernel-fused) is the safe default there.
-    Callers that have validated a GPU lowering can pass ``interpret=False``
-    explicitly (e.g. ``EngineConfig(interpret=False)``)."""
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:  # pragma: no cover
-        return True
+    """Whether Pallas kernels run in interpret mode by default: True on
+    anything that is not a TPU backend (CPU hosts and forced-host-device test
+    meshes).  The kernels are written for Mosaic (TPU); their GPU lowering is
+    untested, so interpret mode is the default there too."""
+    return jax.default_backend() != "tpu"

@@ -118,9 +118,9 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import interpret_default, shard_map
+from repro.compat import interpret_default, make_mesh, shard_map
 from repro.core.execution.exchange_api import make_backend
 from repro.core.execution.pipeline_exchange import (
     bucketed_all_to_all,
@@ -232,7 +232,7 @@ class EngineConfig:
     eps_v: float = 0.05
     hard_bound: int = 4
     seed: int = 0
-    use_pallas: bool = True  # False: pure-jnp gather (debug / tiny graphs)
+    use_pallas: bool = True  # False: the same sums as XLA gathers (debug)
     interpret: Optional[bool] = None  # Pallas interpret mode; None = auto
 
 
@@ -281,7 +281,7 @@ class DistGNNEngine:
         builder = get_layout_builder(cfg.partition_family)
         builder.validate(cfg, partition=partition)
         if mesh is None:
-            mesh = jax.make_mesh((len(jax.devices()),), ("w",))
+            mesh = make_mesh((len(jax.devices()),), ("w",))
         if len(mesh.axis_names) != 1:
             raise ValueError("DistGNNEngine wants a 1D mesh (one axis over "
                              f"all devices); got axes {mesh.axis_names}")
@@ -330,20 +330,18 @@ class DistGNNEngine:
     # ------------------------------------------------------------------
 
     def _ell(self, ids, mask, table):
-        """sum_k mask[v,k] * table[ids[v,k]] — the Pallas ELL kernel (or its
-        jnp oracle): the local multiply AND the replica-combine reduction."""
-        if self.cfg.use_pallas:
-            return ell_spmm(ids, mask, table, normalize=False,
-                            interpret=self.interpret)
-        return (mask[..., None] * jnp.take(table, ids, axis=0)).sum(1)
+        """sum_k mask[v,k] * table[ids[v,k]] — the ELL aggregation kernel:
+        the local multiply AND the replica-combine reduction."""
+        return ell_spmm(ids, mask, table, normalize=False,
+                        interpret=self.interpret,
+                        use_pallas=self.cfg.use_pallas)
 
     def _ell_attend(self, ids, w, table):
         """sum_k w[v,k] * table[ids[v,k]] with gradients to BOTH w and table —
         the GAT aggregation (`_ell`'s VJP treats the mask as structure, but
         attention coefficients are a function of the params)."""
-        if self.cfg.use_pallas:
-            return ell_attend(ids, w, table, interpret=self.interpret)
-        return (w[..., None] * jnp.take(table, ids, axis=0)).sum(1)
+        return ell_attend(ids, w, table, interpret=self.interpret,
+                          use_pallas=self.cfg.use_pallas)
 
     def _sddmm(self, ids, mask, table, a_src, a_dst):
         """Masked GAT edge logits over the ELL structure (Pallas SDDMM or its
@@ -384,6 +382,13 @@ class DistGNNEngine:
         pw = jnp.exp(e_masked - m) * (e_masked > -1e29)
         return pw, pw.sum(1, keepdims=True)
 
+    def _place(self, consts, specs):
+        """Commit the step's constants to the mesh once, with the shardings
+        the step reads them with — uncommitted arrays would be resharded
+        from device 0 on every call."""
+        return jax.device_put(consts, {key: NamedSharding(self.mesh, spec)
+                                       for key, spec in specs.items()})
+
     def _protocol_kwargs(self):
         c = self.cfg
         return dict(staleness=c.staleness, eps=c.eps_v, hard_bound=c.hard_bound)
@@ -396,17 +401,19 @@ class DistGNNEngine:
         key = key if key is not None else jax.random.PRNGKey(self.cfg.seed)
         params = init_gnn_params(self.cfg.model, self.dims, key)
         L = len(self.dims) - 1
+        # historical embeddings only exist under the async protocols; sync
+        # carries one placeholder row per device instead of [Vp, d] zeros
+        hist_rows = self.k if self.cfg.protocol == "sync" else self.Vp
         state = dict(
             params=params,
             step=jnp.zeros((), jnp.int32),
-            hist=tuple(jnp.zeros((self.Vp, d), jnp.float32)
+            hist=tuple(jnp.zeros((hist_rows, d), jnp.float32)
                        for d in self.dims[1:]),
             age=jnp.zeros((L, self.k), jnp.int32),
         )
         # Pre-place with the step's output shardings so feeding the state
         # back in reuses the ONE compiled executable (same contract as
         # init_minibatch_state; enforced by the vertex-cut recompile guard).
-        from jax.sharding import NamedSharding
         ax = self.axis
         rep = NamedSharding(self.mesh, P())
         row = NamedSharding(self.mesh, P(ax))  # == P(ax, None) for 2D, but
@@ -592,7 +599,7 @@ class DistGNNEngine:
             new_state, metrics, logits = smapped(state, consts_)
             return new_state, metrics, logits
 
-        self._consts = consts
+        self._consts = self._place(consts, shard)
         self._jit_step = step
         self._step = lambda state: step(state, self._consts)
         return self._step
@@ -800,7 +807,7 @@ class DistGNNEngine:
         def istep(params, X, consts_):
             return smapped(params, X, consts_)
 
-        self._infer_consts = consts
+        self._infer_consts = consts = self._place(consts, shard)
         self._jit_infer = istep
         self._infer_step = lambda params, X: istep(params, X, consts)
         return self._infer_step
@@ -848,7 +855,10 @@ class DistGNNEngine:
                     return H
 
                 self._ref_infer = ref_infer
-            return self._ref_infer(params, X)
+            # one device, as in train(reference=True)
+            out = self._ref_infer(*jax.device_put(
+                (params, X), self.mesh.devices.flat[0]))
+            return jax.device_put(out, NamedSharding(self.mesh, P()))
         with self.telemetry.span("infer_sweep"):
             out = self.make_infer_step()(params, X)
             with self._account_exchange("inference", None, None):
@@ -1107,7 +1117,6 @@ class DistGNNEngine:
         # Pre-place replicated, matching the step's output sharding — so
         # feeding the state back in reuses the ONE compiled executable
         # (the recompile-count contract in tests/test_engine_minibatch.py).
-        from jax.sharding import NamedSharding
         state = jax.device_put(state, NamedSharding(self.mesh, P()))
         if self.cfg.trainable_features:
             # layer-0 rows are parameters: the store table plus owner-sharded
@@ -1306,7 +1315,7 @@ class DistGNNEngine:
         def step(state, consts_, batch):
             return smapped(state, consts_, batch)
 
-        self._mb_consts = consts
+        self._mb_consts = self._place(consts, cshard)
         self._jit_mb_step = step
         self._mb_step = lambda state, batch: step(state, self._mb_consts, batch)
         return self._mb_step
@@ -1551,6 +1560,10 @@ class DistGNNEngine:
             return losses, logits
         step = self.make_reference_step() if reference else self.make_step()
         state = self.init_state()
+        if reference:
+            # the oracle runs on one device: a state left on a multi-device
+            # mesh would partition its Pallas kernels, which Mosaic refuses
+            state = jax.device_put(state, self.mesh.devices.flat[0])
         if not reference and (self._wire_fields
                               or self.cfg.trainable_features):
             self.comm_stats.reset()
@@ -1570,6 +1583,10 @@ class DistGNNEngine:
                             self._emb_bytes_per_step
                 tel.log_step(step=i, loss=losses[-1],
                              comm_total_bytes=self.comm_stats.total())
+        if reference:
+            # hand the oracle's logits back on the mesh, comparable with the
+            # distributed step's
+            logits = jax.device_put(logits, NamedSharding(self.mesh, P()))
         return losses, logits
 
     def accuracy(self, logits, split: str = "test") -> float:

@@ -59,7 +59,7 @@ from repro.core.partition.cost_models import (
     model_exchange_widths,
     replica_sync_device_bytes,
 )
-from repro.core.partition.edge_cut import PARTITIONERS
+from repro.core.partition.edge_cut import PARTITIONERS, Partition
 from repro.core.partition.vertex_cut import VERTEX_CUTS
 from repro.core.partition.vertex_layout import build_vertex_layout
 
@@ -140,6 +140,8 @@ class EdgeCutLayout(PartitionLayout):
     supports_minibatch = True
 
     def _build(self, partition):
+        if partition is None and self.k == 1:  # one part: nothing to cut
+            partition = Partition(np.zeros(self.g.num_vertices, np.int32), 1)
         self.part = (partition
                      or PARTITIONERS[self.cfg.partitioner](self.g, self.k))
         self._build_vertex_blocks()
@@ -278,22 +280,15 @@ class EdgeCutLayout(PartitionLayout):
         ids_remap = np.full((Vp, K), nb + B * k * w, np.int32)
         for d in range(k):
             rows = slice(d * nb, (d + 1) * nb)
-            pos_lut = {}  # (src, local_id) -> halo slot
-            for s in range(k):
-                for t, li in enumerate(need_sets[d][s]):
-                    pos_lut[(s, int(li))] = int(halo_slot(t, s, w, k, nb))
-            id_blk = ids[rows]
-            sp_blk = src_part[rows]
-            li_blk = local_id[rows]
+            sp_blk, li_blk = src_part[rows], local_id[rows]
             out = ids_remap[rows]
-            for r in range(nb):
-                for c in range(K):
-                    if id_blk[r, c] >= Vp:
-                        continue
-                    s = sp_blk[r, c]
-                    out[r, c] = (li_blk[r, c] if s == d
-                                 else pos_lut[(s, int(li_blk[r, c]))])
-            ids_remap[rows] = out
+            for s in range(k):
+                sel = sp_blk == s  # pads (id >= Vp) have src_part -1
+                if s == d:
+                    out[sel] = li_blk[sel]
+                else:  # halo row t = li's rank in the sorted need list
+                    t = np.searchsorted(need_sets[d][s], li_blk[sel])
+                    out[sel] = halo_slot(t, s, w, k, nb)
         self.ids_exec = jnp.asarray(ids_remap)
 
     # -- engine-facing interface -------------------------------------------

@@ -272,7 +272,12 @@ def _worker_main(rank: int, produce: Callable, slot_names: Sequence[str],
     wait for slot ``idx % depth``'s turn, write arrays, post metadata.
 
     Tasks arrive as CHUNKS (lists of (idx, item) pairs) so an epoch costs
-    O(chunks) queue round-trips, not O(batches)."""
+    O(chunks) queue round-trips, not O(batches).
+
+    Workers are host-only: the producer import chain is jax-free, and the
+    worker pins ``JAX_PLATFORMS=cpu`` before producing, so nothing it
+    imports later can bring up (and hold) the parent's accelerator."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
     depth = len(slot_names)
     slots = [_attach_shm(n) for n in slot_names]
     views = [_slot_views(s.buf, table) for s in slots]

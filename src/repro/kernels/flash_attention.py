@@ -12,8 +12,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.compat import pallas_block_slice
-
 NEG_INF = -1e30
 
 
@@ -28,12 +26,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block: int, causal: bool,
 
     def body(kv_i, carry):
         m, l, acc = carry
-        # leading block dim indexed with a width-1 slice, not a bare int:
-        # jax 0.4.3x interpret-mode load discharge requires Slice/array indices
-        k_blk = pl.load(k_ref, (pallas_block_slice(0, 1),
-                                pl.dslice(kv_i * kv_block, kv_block), slice(None)))[0]
-        v_blk = pl.load(v_ref, (pallas_block_slice(0, 1),
-                                pl.dslice(kv_i * kv_block, kv_block), slice(None)))[0]
+        k_blk = k_ref[0, pl.ds(kv_i * kv_block, kv_block), :]
+        v_blk = v_ref[0, pl.ds(kv_i * kv_block, kv_block), :]
         s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:

@@ -1,8 +1,14 @@
 """Pallas TPU kernel: SDDMM-style GAT edge scores on ELL structure.
 
-e[v, k] = LeakyReLU(a_dst . Hw[v]  +  a_src . Hw[ids[v, k]]), masked -> -inf.
-The dense-dense products (Hw @ a) ride the VPU; the per-edge combine is a
-gather + add over the ELL lanes. Grid over row blocks; Hw resident per block.
+e[v, k] = LeakyReLU(a_dst . Hw[v]  +  a_src . Hw[ids[v, k]]), masked -> -1e30.
+
+The score is separable: each row's two coefficients s_src = Hw @ a_src and
+s_dst = Hw @ a_dst are one matrix-vector product over the table, so an edge
+needs one gathered scalar, never a gathered feature row.  The products and
+the [V, K] scalar gather run in XLA; the kernel fuses the per-edge combine
+(add, LeakyReLU, mask) over row blocks of the ELL slot grid.  Nothing in it
+scales with the table: per program it holds [rb, K] scores and [rb, 1]
+destination coefficients.
 """
 from __future__ import annotations
 
@@ -12,49 +18,42 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.utils import round_up
 
-def _sddmm_kernel(ids_ref, mask_ref, hw_ref, asrc_ref, adst_ref, out_ref, *,
-                  slope: float):
-    ids = ids_ref[...]  # [Rb, K]
-    mask = mask_ref[...]
-    hw = hw_ref[...]  # [N, D]
-    a_src = asrc_ref[...]  # [1, D]
-    a_dst = adst_ref[...]
-    s_all_src = jnp.sum(hw * a_src, axis=1)  # [N]
-    s_all_dst = jnp.sum(hw * a_dst, axis=1)  # [N]
-    rb = ids.shape[0]
-    i = pl.program_id(0)
-    row_ids = i * rb + jax.lax.broadcasted_iota(jnp.int32, (rb,), 0)
-    s_dst = jnp.take(s_all_dst, row_ids, axis=0)  # [Rb]
-    s_src = jnp.take(s_all_src, ids.reshape(-1), axis=0).reshape(ids.shape)  # [Rb,K]
-    e = s_dst[:, None] + s_src
+
+def _sddmm_kernel(snbr_ref, sdst_ref, mask_ref, out_ref, *, slope: float):
+    e = sdst_ref[...] + snbr_ref[...]  # [rb, 1] + [rb, K]
     e = jnp.where(e > 0, e, slope * e)
-    out_ref[...] = jnp.where(mask > 0, e, -1e30).astype(out_ref.dtype)
+    out_ref[...] = jnp.where(mask_ref[...] > 0, e, -1e30).astype(out_ref.dtype)
 
 
 def sddmm_pallas(ids: jnp.ndarray, mask: jnp.ndarray, Hw: jnp.ndarray,
                  a_src: jnp.ndarray, a_dst: jnp.ndarray, *, slope: float = 0.2,
                  row_block: int = 128, interpret: bool = False) -> jnp.ndarray:
+    """Masked edge logits; destination row v is table row v (the table's
+    first V rows are the dst rows).  Rows are padded to the grid."""
     V, K = ids.shape
-    N, D = Hw.shape
-    row_block = min(row_block, V)
-    assert V % row_block == 0
-    grid = (V // row_block,)
-    kernel = functools.partial(_sddmm_kernel, slope=slope)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    s_nbr = jnp.take(Hw @ a_src, ids, axis=0)  # [V, K]
+    s_dst = (Hw[:V] @ a_dst)[:, None]  # [V, 1]
+    rb = max(8, min(row_block, round_up(V, 8)))
+    Vp = round_up(V, rb)
+    mask = mask.astype(jnp.float32)
+    if Vp != V:  # pad rows: mask 0 -> -1e30 logits, sliced away
+        pad = ((0, Vp - V), (0, 0))
+        s_nbr, s_dst, mask = (jnp.pad(x, pad) for x in (s_nbr, s_dst, mask))
+    out = pl.pallas_call(
+        functools.partial(_sddmm_kernel, slope=slope),
+        grid=(Vp // rb,),
         in_specs=[
-            pl.BlockSpec((row_block, K), lambda i: (i, 0)),
-            pl.BlockSpec((row_block, K), lambda i: (i, 0)),
-            pl.BlockSpec((N, D), lambda i: (0, 0)),
-            pl.BlockSpec((1, D), lambda i: (0, 0)),
-            pl.BlockSpec((1, D), lambda i: (0, 0)),
+            pl.BlockSpec((rb, K), lambda i: (i, 0)),
+            pl.BlockSpec((rb, 1), lambda i: (i, 0)),
+            pl.BlockSpec((rb, K), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((row_block, K), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((V, K), jnp.float32),
+        out_specs=pl.BlockSpec((rb, K), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((Vp, K), jnp.float32),
         interpret=interpret,
-    )(ids, mask.astype(jnp.float32), Hw, a_src.reshape(1, -1), a_dst.reshape(1, -1))
+    )(s_nbr.astype(jnp.float32), s_dst.astype(jnp.float32), mask)
+    return out[:V] if Vp != V else out
 
 
 # ---------------------------------------------------------------------------
@@ -73,27 +72,15 @@ def sddmm_pallas(ids: jnp.ndarray, mask: jnp.ndarray, Hw: jnp.ndarray,
 # grid here, so any V works.
 
 
-def _sddmm_padded(ids, mask, Hw, a_src, a_dst, slope, row_block, interpret):
-    V, K = ids.shape
-    rb = min(row_block, V)
-    Vp = -(-V // rb) * rb
-    if Vp != V:  # pad rows: ids 0 / mask 0 -> -1e30 logits, sliced away
-        ids = jnp.concatenate([ids, jnp.zeros((Vp - V, K), ids.dtype)], 0)
-        mask = jnp.concatenate([mask, jnp.zeros((Vp - V, K), mask.dtype)], 0)
-    out = sddmm_pallas(ids, mask, Hw, a_src, a_dst, slope=slope,
-                       row_block=rb, interpret=interpret)
-    return out[:V] if Vp != V else out
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _sddmm_vjp(slope, row_block, interpret, ids, mask, Hw, a_src, a_dst):
-    return _sddmm_padded(ids, mask, Hw, a_src, a_dst, slope, row_block,
-                         interpret)
+    return sddmm_pallas(ids, mask, Hw, a_src, a_dst, slope=slope,
+                        row_block=row_block, interpret=interpret)
 
 
 def _sddmm_fwd(slope, row_block, interpret, ids, mask, Hw, a_src, a_dst):
-    out = _sddmm_padded(ids, mask, Hw, a_src, a_dst, slope, row_block,
-                        interpret)
+    out = sddmm_pallas(ids, mask, Hw, a_src, a_dst, slope=slope,
+                       row_block=row_block, interpret=interpret)
     return out, (ids, mask, Hw, a_src, a_dst)
 
 

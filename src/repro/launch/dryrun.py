@@ -22,13 +22,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import cost_analysis as compat_cost_analysis
-from repro.compat import peak_memory_in_bytes as compat_peak_memory
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, get_config, get_shape, supports_shape
 from repro.data.pipeline import batch_logical_axes, input_specs
 from repro.launch import flops as flops_lib
 from repro.launch.hlo_analysis import collective_bytes, roofline_terms
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.serve import decode_rules_overrides, serve_options_for
 from repro.launch.sharding import make_rules, sharding_for_tree, use_rules
 from repro.launch.train import default_optimizer, make_train_state_specs
@@ -179,7 +177,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Optional[s
                                               extra_rules=extra_rules,
                                               opts_set=opts_set)
         ma = compiled.memory_analysis()
-        ca = compat_cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         txt = compiled.as_text()
         coll_total, coll_by_kind = collective_bytes(txt)
         analytic = flops_lib.step_flops(cfg, shape, window=window)
@@ -189,7 +187,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Optional[s
             cfg, shape, chips=chips, param_bytes_total=param_bytes_total,
             cache_bytes_total=meta.get("cache_bytes", 0))
         rl = roofline_terms(
-            analytic_flops=analytic.total, chips=chips,
+            device_kind=PRODUCTION_DEVICE_KIND, analytic_flops=analytic.total, chips=chips,
             hbm_bytes_per_chip=hbm_traffic,
             collective_bytes_per_chip=coll_total,
             model_flops=model_fl, hlo_flops_raw=float(ca.get("flops", 0.0)))
@@ -204,7 +202,7 @@ def run_pair(arch: str, shape_name: str, *, multi_pod: bool, out_dir: Optional[s
                 "argument_bytes_per_device": ma.argument_size_in_bytes,
                 "output_bytes_per_device": ma.output_size_in_bytes,
                 "temp_bytes_per_device": ma.temp_size_in_bytes,
-                "peak_bytes_per_device": compat_peak_memory(ma),
+                "peak_bytes_per_device": ma.peak_memory_in_bytes,
                 "alias_bytes_per_device": ma.alias_size_in_bytes,
             },
             cost_analysis={k: ca[k] for k in ("flops", "bytes accessed") if k in ca},

@@ -23,11 +23,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import cost_analysis as compat_cost_analysis
-from repro.compat import peak_memory_in_bytes as compat_peak_memory
+from repro.compat import make_mesh
 from repro.configs.gcn_paper import CONFIG as GNN_CFG
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import collective_bytes, roofline_terms
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.utils import get_logger, human_bytes
 
 log = get_logger("repro.dryrun_gnn")
@@ -270,7 +270,7 @@ def run_autotune(args):
             num_classes=cfg.num_classes, seed=0)
     dims = ([cfg.feature_dim] + [cfg.hidden_dim] * (cfg.num_layers - 1)
             + [cfg.num_classes])
-    mesh = jax.make_mesh((k,), ("w",))
+    mesh = make_mesh((k,), ("w",))
     t0 = time.time()
     plan, report = autotune(g, k, dims, args.engine_model, mesh=mesh)
     val = report["validation"]
@@ -368,6 +368,7 @@ def main():
                     "sweep across graphs x chips) and exit")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = GNN_CFG
     if args.bench_partition_families:
         dims = ([cfg.feature_dim] + [cfg.hidden_dim] * (cfg.num_layers - 1)
@@ -412,7 +413,7 @@ def main():
         g = gfn(args.engine_vertices, avg_degree=cfg.avg_degree,
                 feature_dim=cfg.feature_dim,
                 num_classes=cfg.num_classes, seed=0)
-        mesh1d = jax.make_mesh((chips,), ("w",))
+        mesh1d = make_mesh((chips,), ("w",))
         minibatch = args.engine_batching != "full_graph"
         ecfg = EngineConfig(
             execution=args.engine_exec, model=args.engine_model,
@@ -623,7 +624,7 @@ def main():
                                specs["y"], specs["train_w"])
         compiled = lowered.compile()
     ma = compiled.memory_analysis()
-    ca = compat_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     coll, kinds = collective_bytes(compiled.as_text())
     mesh_name = "pod2x16x16" if args.multi_pod else "pod16x16"
     # analytic: per layer 2*E*D (aggregation) + 2*V*D_in*D_out, x3 for train
@@ -631,7 +632,7 @@ def main():
     for a, b in zip(dims[:-1], dims[1:]):
         fl += 2.0 * V * K * a + 2.0 * V * a * b
     fl *= 3.0
-    rl = roofline_terms(analytic_flops=fl, chips=chips,
+    rl = roofline_terms(device_kind=PRODUCTION_DEVICE_KIND, analytic_flops=fl, chips=chips,
                         hbm_bytes_per_chip=(V * D * 4 * 3) / chips,
                         collective_bytes_per_chip=coll,
                         model_flops=fl, hlo_flops_raw=float(ca.get("flops", 0)))
@@ -641,7 +642,7 @@ def main():
                   memory=dict(argument_bytes_per_device=ma.argument_size_in_bytes,
                               temp_bytes_per_device=ma.temp_size_in_bytes,
                               output_bytes_per_device=ma.output_size_in_bytes,
-                              peak_bytes_per_device=compat_peak_memory(ma),
+                              peak_bytes_per_device=ma.peak_memory_in_bytes,
                               alias_bytes_per_device=ma.alias_size_in_bytes),
                   cost_analysis={k: ca[k] for k in ("flops", "bytes accessed") if k in ca},
                   collective_bytes_per_device=coll, collective_by_kind=kinds,

@@ -26,10 +26,31 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-# TPU v5e-class hardware constants (per chip), per the assignment.
-PEAK_FLOPS_BF16 = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops_bf16: float  # FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s, chip-to-chip
+
+
+# Per-chip peaks, keyed by ``jax.Device.device_kind``.
+# TPU v5e: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s
+# HBM).  ici_bw is one link's share of the chip's 1,600 Gbit/s ICI total: a
+# ring or all_to_all over one mesh axis does not get every link.
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The peak table's row for ``device_kind``; a kind it lacks is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       f" known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
@@ -186,10 +207,8 @@ def executable_summary(compiled) -> Dict[str, object]:
             default=0),
     }
     try:
-        from repro.compat import peak_memory_in_bytes
-
-        out["peak_memory_bytes"] = peak_memory_in_bytes(
-            compiled.memory_analysis())
+        out["peak_memory_bytes"] = int(
+            compiled.memory_analysis().peak_memory_in_bytes)
     except Exception:  # pragma: no cover — backend without memory stats
         pass
     return out
@@ -215,12 +234,14 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def roofline_terms(*, analytic_flops: float, chips: int, hbm_bytes_per_chip: float,
+def roofline_terms(*, device_kind: str, analytic_flops: float, chips: int,
+                   hbm_bytes_per_chip: float,
                    collective_bytes_per_chip: float, model_flops: float,
                    hlo_flops_raw: float) -> Roofline:
-    compute_s = analytic_flops / (chips * PEAK_FLOPS_BF16)
-    memory_s = hbm_bytes_per_chip / HBM_BW
-    coll_s = collective_bytes_per_chip / ICI_BW
+    pk = peaks_for(device_kind)
+    compute_s = analytic_flops / (chips * pk.flops_bf16)
+    memory_s = hbm_bytes_per_chip / pk.hbm_bw
+    coll_s = collective_bytes_per_chip / pk.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dominant = max(terms, key=terms.get)
     return Roofline(compute_s, memory_s, coll_s, model_flops, hlo_flops_raw,

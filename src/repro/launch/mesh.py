@@ -2,21 +2,14 @@
 module never touches jax device state."""
 from __future__ import annotations
 
-import jax
+from repro.compat import make_mesh
+
+# The chip the production meshes are sized for, as ``device_kind`` names it
+# (the dry-runs compile on CPU placeholders and read peaks for this kind).
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (possibly forced) host devices exist."""
-    n = len(jax.devices())
-    assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh(shape, axes)

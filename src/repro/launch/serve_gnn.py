@@ -22,6 +22,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compat import make_mesh
 from repro.core.engine import (
     EXECUTION_MODELS,
     GNN_MODELS,
@@ -30,6 +31,7 @@ from repro.core.engine import (
 )
 from repro.core.graph import sbm_graph
 from repro.core.serving import GNNQueryEngine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.utils import get_logger
 
 log = get_logger("repro.serve_gnn")
@@ -51,7 +53,7 @@ def build_engine(args, g):
     n_dev = len(jax.devices())
     k = args.parts or n_dev
     assert k <= n_dev, f"need {k} devices, have {n_dev} (set XLA_FLAGS)"
-    mesh = jax.make_mesh((k,), ("w",))
+    mesh = make_mesh((k,), ("w",))
     return DistGNNEngine(g, mesh=mesh, cfg=cfg)
 
 
@@ -118,6 +120,7 @@ def main():
     ap.add_argument("--targets-per-query", type=int, default=8)
     ap.add_argument("--oracle-check", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     g = sbm_graph(args.vertices, num_blocks=8, p_in=0.05, p_out=0.003, seed=0)
     eng = build_engine(args, g)

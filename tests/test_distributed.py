@@ -9,6 +9,7 @@ from conftest import run_with_devices
 def test_spmm_models_match_oracle_8dev():
     out = run_with_devices("""
         import jax, numpy as np, jax.numpy as jnp
+        from repro.compat import make_mesh
         from repro.core.graph import er_graph
         from repro.core.execution.spmm_models import (spmm_replicated,
             spmm_1d_broadcast, spmm_1d_ring, spmm_1d_p2p, spmm_2d_summa,
@@ -18,8 +19,8 @@ def test_spmm_models_match_oracle_8dev():
         H_np = np.random.default_rng(0).standard_normal((64, 16)).astype(np.float32)
         ref = A_np @ H_np
         A, H = jnp.asarray(A_np), jnp.asarray(H_np)
-        m1 = jax.make_mesh((8,), ("w",))
-        m2 = jax.make_mesh((4, 2), ("r", "c"))
+        m1 = make_mesh((8,), ("w",))
+        m2 = make_mesh((4, 2), ("r", "c"))
         for name, fn, mesh in [("replicated", spmm_replicated, m1),
                                ("1d", spmm_1d_broadcast, m1),
                                ("ring", spmm_1d_ring, m1),
@@ -39,6 +40,7 @@ def test_moe_expert_parallel_matches_reference_4dev():
     out = run_with_devices("""
         import dataclasses
         import jax, numpy as np, jax.numpy as jnp
+        from repro.compat import make_mesh
         from repro.configs import get_smoke_config
         from repro.models.moe import moe_apply, moe_params, _moe_reference
         from repro.models.layers import ParamBuilder
@@ -50,7 +52,7 @@ def test_moe_expert_parallel_matches_reference_4dev():
         x = jnp.asarray(np.random.default_rng(1).standard_normal((4, 16, cfg.d_model)) * 0.1,
                         jnp.float32)
         y_ref, aux_ref = _moe_reference(p, x, cfg)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = make_rules(cfg, mesh)
         with use_rules(mesh, rules):
             y_ep, aux_ep = jax.jit(lambda p, x: moe_apply(p, x, cfg))(p, x)
@@ -66,6 +68,7 @@ def test_moe_expert_parallel_matches_reference_4dev():
 def test_sharded_train_step_runs_8dev():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from repro.configs import get_smoke_config
         from repro.configs.base import ShapeConfig
         from repro.launch.train import (default_optimizer, init_train_state,
@@ -73,7 +76,7 @@ def test_sharded_train_step_runs_8dev():
         from repro.data.pipeline import make_batch
         cfg = get_smoke_config("llama3.2-1b")
         shape = ShapeConfig("tiny_train", 64, 8, "train")
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         opt = default_optimizer(cfg)
         step, state_sh, batch_sh, rules = make_sharded_train_step(cfg, opt, mesh, shape)
         state = init_train_state(cfg, opt, jax.random.PRNGKey(0))
@@ -93,10 +96,11 @@ def test_sharded_train_step_runs_8dev():
 def test_flash_decode_seq_sharded_cache_8dev():
     out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.compat import make_mesh
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.models.layers import decode_attention, flash_decode_sharded
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         B, H, T, D = 1, 4, 64, 16
         rng = np.random.default_rng(0)
         q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
@@ -140,6 +144,7 @@ def test_manual_tp_block_matches_plain_4dev():
     out = run_with_devices("""
         import dataclasses
         import jax, numpy as np, jax.numpy as jnp
+        from repro.compat import make_mesh
         from repro.configs import get_smoke_config
         from repro.models import transformer as T
         from repro.launch.sharding import make_rules, use_rules
@@ -151,7 +156,7 @@ def test_manual_tp_block_matches_plain_4dev():
                  "labels": jnp.zeros((B,S), jnp.int32),
                  "positions": jnp.broadcast_to(jnp.arange(S)[None], (B,S))}
         loss_plain, _ = T.loss_fn(cfg, params, batch)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         rules = make_rules(cfg, mesh, {"act_res_seq": "model", "_manual_tp": True})
         with use_rules(mesh, rules):
             loss_tp, _ = jax.jit(lambda p, b: T.loss_fn(cfg, p, b))(params, batch)
@@ -166,6 +171,7 @@ def test_moe_dedup_and_2d_decode_match_reference_4dev():
     out = run_with_devices("""
         import dataclasses
         import jax, numpy as np, jax.numpy as jnp
+        from repro.compat import make_mesh
         from repro.configs import get_smoke_config
         from repro.models.moe import moe_apply, moe_params, _moe_reference
         from repro.models.layers import ParamBuilder
@@ -177,7 +183,7 @@ def test_moe_dedup_and_2d_decode_match_reference_4dev():
         x = jnp.asarray(np.random.default_rng(1).standard_normal((4, 8, base.d_model)) * 0.1,
                         jnp.float32)
         y_ref, _ = _moe_reference(p, x, base)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         # dedup dispatch, full groups (math-identical)
         cfg = dataclasses.replace(base, moe_group_limit=2)
         with use_rules(mesh, make_rules(cfg, mesh)):
@@ -200,6 +206,7 @@ def test_mla_seqsharded_decode_matches_dense_4dev():
     out = run_with_devices("""
         import dataclasses
         import jax, numpy as np, jax.numpy as jnp
+        from repro.compat import make_mesh
         from repro.configs import get_smoke_config
         from repro.models import layers as L
         from repro.launch.sharding import make_rules, use_rules
@@ -213,7 +220,7 @@ def test_mla_seqsharded_decode_matches_dense_4dev():
         kr = jnp.asarray(rng.standard_normal((B,T,cfg.rope_head_dim))*0.1, jnp.float32)
         pos = jnp.int32(9)
         y_ref, c_ref, kr_ref = L.mla_decode(p, x, c, kr, pos, cfg)
-        mesh = jax.make_mesh((2,2), ("data","model"))
+        mesh = make_mesh((2,2), ("data","model"))
         rules = make_rules(cfg, mesh, {"act_kv_seq": ("model",), "kv_lora": None})
         with use_rules(mesh, rules):
             y2, c2, kr2 = jax.jit(lambda *a: L.mla_decode_seqsharded(*a, cfg))(p, x, c, kr, pos)

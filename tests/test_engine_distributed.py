@@ -10,6 +10,7 @@ may not change the math — only where it runs.
 import pytest
 
 from conftest import run_with_devices
+from repro.compat import make_mesh
 
 _MATRIX_CODE = """
     import itertools
@@ -28,7 +29,13 @@ _MATRIX_CODE = """
                            hidden=16, lr=0.3)
         eng = DistGNNEngine(g, cfg=cfg)
         losses_d, logits_d = eng.train({epochs})
+        # the oracle step must see a one-device state: on a TPU mesh a
+        # Mosaic kernel in a multi-device jit is refused
+        ref_step, ref_devices = eng.make_reference_step(), set()
+        eng._ref_step = lambda s: (
+            ref_devices.update(s["step"].sharding.device_set), ref_step(s))[1]
         losses_r, logits_r = eng.train({epochs}, reference=True)
+        assert len(ref_devices) == 1, ref_devices
         err = max(abs(a - b) for a, b in zip(losses_d, losses_r))
         lerr = float(abs(logits_d - logits_r).max())
         tag = f"{{exe}}/{{proto}}/{{cfg.partitioner}}"
@@ -105,7 +112,7 @@ def test_engine_single_device_paths_agree():
     from repro.core.graph import sbm_graph
 
     g = sbm_graph(64, num_blocks=4, p_in=0.1, p_out=0.01, seed=1)
-    mesh = jax.make_mesh((1,), ("w",))
+    mesh = make_mesh((1,), ("w",))
     eng = DistGNNEngine(g, mesh=mesh, cfg=EngineConfig(
         execution="p2p", protocol="sync", hidden=16, lr=0.3))
     ld, _ = eng.train(10)

@@ -16,6 +16,7 @@ oracle, config validation, and the single-device degeneration.
 import pytest
 
 from conftest import run_with_devices
+from repro.compat import make_mesh
 
 _MATRIX_CODE = """
     import itertools
@@ -206,7 +207,7 @@ def test_hybrid_single_device_paths_agree():
     from repro.core.graph import sbm_graph
 
     g = sbm_graph(64, num_blocks=4, p_in=0.1, p_out=0.01, seed=1)
-    mesh = jax.make_mesh((1,), ("w",))
+    mesh = make_mesh((1,), ("w",))
     eng = DistGNNEngine(g, mesh=mesh, cfg=EngineConfig(
         partition_family="hybrid", execution="p2p", hidden=16, lr=0.3))
     ld, _ = eng.train(8)

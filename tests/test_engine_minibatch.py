@@ -13,6 +13,7 @@ engine's reported feature bytes and the standalone
 import pytest
 
 from conftest import run_with_devices
+from repro.compat import make_mesh
 
 _MATRIX_CODE = """
     import itertools
@@ -250,7 +251,7 @@ def test_minibatch_single_device_paths_agree():
     from repro.core.graph import sbm_graph
 
     g = sbm_graph(64, num_blocks=4, p_in=0.1, p_out=0.01, seed=1)
-    mesh = jax.make_mesh((1,), ("w",))
+    mesh = make_mesh((1,), ("w",))
     eng = DistGNNEngine(g, mesh=mesh, cfg=EngineConfig(
         execution="p2p", batching="node_wise", batch_size=8, fanouts=(3, 3),
         hidden=16, lr=0.3, cache_policy="static_degree", cache_capacity=8))

@@ -17,6 +17,7 @@ loss-identical to the monolithic plan).
 import pytest
 
 from conftest import run_with_devices
+from repro.compat import make_mesh
 
 _FULL_GRAPH_CODE = """
     import itertools
@@ -242,7 +243,7 @@ def test_stale_protocol_config_fails_fast():
     from repro.core.graph import er_graph
 
     g = er_graph(32, avg_degree=4, seed=0)
-    mesh = jax.make_mesh((1,), ("w",))
+    mesh = make_mesh((1,), ("w",))
     eng = DistGNNEngine(g, mesh=mesh, cfg=EngineConfig(
         batching="node_wise", batch_size=4, fanouts=(2, 2), hidden=8))
     eng.cfg.protocol = "epoch_adaptive"  # stale mutation
@@ -268,7 +269,7 @@ def test_model_single_device_paths_agree():
     from repro.core.graph import sbm_graph
 
     g = sbm_graph(64, num_blocks=4, p_in=0.1, p_out=0.01, seed=1)
-    mesh = jax.make_mesh((1,), ("w",))
+    mesh = make_mesh((1,), ("w",))
     for model in ("sage", "gat", "gin"):
         eng = DistGNNEngine(g, mesh=mesh, cfg=EngineConfig(
             model=model, execution="p2p", hidden=16, lr=0.2))

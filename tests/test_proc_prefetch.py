@@ -41,6 +41,13 @@ def _produce(i):
             {"item": i, "sample_seconds": 0.0, "extract_seconds": 0.0})
 
 
+def _produce_env(i):
+    arrays, meta = _produce(i)
+    meta.update(jax_loaded="jax" in sys.modules,
+                platforms=os.environ.get("JAX_PLATFORMS"))
+    return arrays, meta
+
+
 def _shm_litter():
     return [f for f in os.listdir("/dev/shm") if f.startswith("repro-")]
 
@@ -299,6 +306,19 @@ def test_shared_graph_roundtrip_and_worker_jax_hygiene():
                           text=True, timeout=120, env=env)
     assert proc.returncode == 0, proc.stderr
     assert "JAX_FREE" in proc.stdout
+
+
+def test_workers_never_load_jax_and_are_pinned_to_cpu():
+    """A sampling worker must not bring up an accelerator backend: a chip
+    belongs to the one process that holds it.  Workers never import jax,
+    and run with JAX_PLATFORMS=cpu in case a producer ever does."""
+    pool = ProcPrefetchPool(_produce_env, LAYOUT, depth=2, num_workers=2)
+    try:
+        metas = [o[2] for o in pool.run(list(range(4)))]
+    finally:
+        pool.close()
+    assert [m["jax_loaded"] for m in metas] == [False] * 4
+    assert [m["platforms"] for m in metas] == ["cpu"] * 4
 
 
 def test_no_leaked_shm_warnings_at_interpreter_exit():

@@ -145,7 +145,8 @@ def test_collective_parser_counts_scan_trips():
     code = """
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-mesh = jax.make_mesh((4,), ("x",))
+from repro.compat import make_mesh
+mesh = make_mesh((4,), ("x",))
 def f(h):
     def body(c, x):
         return c + jax.lax.psum(x, "x"), None
@@ -170,11 +171,18 @@ print(comp.as_text())
 
 
 def test_roofline_terms_dominance():
-    rl = roofline_terms(analytic_flops=1e18, chips=256, hbm_bytes_per_chip=1e9,
+    rl = roofline_terms(device_kind="TPU v5 lite", analytic_flops=1e18, chips=256, hbm_bytes_per_chip=1e9,
                         collective_bytes_per_chip=1e8, model_flops=8e17,
                         hlo_flops_raw=1e13)
     assert rl.dominant == "compute"
     assert 0 < rl.useful_ratio < 1
+
+
+def test_roofline_peaks_refuse_unknown_device_kind():
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline_terms(device_kind="cpu", analytic_flops=1.0, chips=1,
+                       hbm_bytes_per_chip=1.0, collective_bytes_per_chip=1.0,
+                       model_flops=1.0, hlo_flops_raw=1.0)
 
 
 # --- analytic flops ---------------------------------------------------------
@@ -194,10 +202,8 @@ def test_analytic_flops_vs_cost_analysis_single_layer():
     batch = {"tokens": jnp.ones((B, S), jnp.int32),
              "labels": jnp.zeros((B, S), jnp.int32),
              "positions": jnp.broadcast_to(jnp.arange(S)[None], (B, S))}
-    from repro.compat import cost_analysis
-
     comp = jax.jit(lambda p, b: T.loss_fn(cfg, p, b)).lower(params, batch).compile()
-    hlo_flops = cost_analysis(comp)["flops"]
+    hlo_flops = comp.cost_analysis()["flops"]
     analytic = flops_lib.forward_flops(cfg, B, S).total
     # forward-only analytic should be within ~2.5x of XLA's forward count
     # (XLA counts masks/softmax/etc., we count matmuls+attention)
