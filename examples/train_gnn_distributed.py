@@ -36,25 +36,29 @@ Run with forced host devices to see real collectives on CPU:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \
     PYTHONPATH=src python examples/train_gnn_distributed.py --exec p2p --protocol epoch_adaptive
 
-Reading a trace (``--trace-out t.json``, engine path):
+Reading a trace (``--trace-out DIR``, engine path):
 
-Pass ``--trace-out t.json`` to record run-wide telemetry and write a Chrome
-trace-event file — open it in Perfetto (https://ui.perfetto.dev) or
-``chrome://tracing``.  What you see:
+Pass ``--trace-out DIR`` to record run-wide telemetry and run the engine
+under ``jax.profiler.trace(DIR)`` — open the trace in TensorBoard's profile
+plugin or Perfetto (https://ui.perfetto.dev).  What you see:
 
-* one **row per lane** (thread): with ``--schedule pipelined`` the prefetch
-  thread's ``sample``/``extract`` spans overlap the trainer lane's ``train``
-  spans — the §6.1 overlap is directly visible as stacked rows;
+* the engine's spans on the host's thread lines, on the device ops' clock:
+  the layout build (``layout.partition`` / ``.vertex_blocks`` / ``.store``
+  / ``.exchange_plan``), ``step.place_consts``, then one ``train`` per
+  step; with ``--schedule pipelined`` the prefetch thread's
+  ``sample``/``extract`` spans overlap the trainer's ``train`` spans — the
+  §6.1 overlap is directly visible as stacked rows;
 * per-device ``sample_device`` child spans under each ``sample`` span, so a
   straggler partition shows up as one long bar (the workload-imbalance
   challenge, survey §2);
-* zero-duration ``exchange`` instants carrying the wire-byte delta of each
-  CommStats mutation in their args — their summed ``bytes`` equal
-  ``CommStats.total()`` exactly;
-* click any span: ``args`` holds step / device / bytes labels.
+* each device op named by its scope inside the jitted step: ``layer<l>/
+  aggregate`` (the ``gather_sum`` kernel; its backward under
+  ``transpose(...)``), ``layer<l>/combine``, ``loss``, ``grad_sync``,
+  ``sgd``, and ``exchange`` around every collective;
+* click any span: its arguments hold the step / device labels.
 
 A step log (one JSON line per step: loss, cumulative comm bytes) is written
-next to the trace as ``<trace-out>.steps.jsonl``, and a run summary —
+to ``DIR/steps.jsonl``, and a run summary —
 per-stage seconds, per-device imbalance ratios (max/mean), metric totals,
 and the compiled step's static collective bytes + peak memory from
 ``hlo_analysis.executable_summary`` — prints at exit.  Telemetry is
@@ -62,6 +66,8 @@ off-by-default and adds <5% overhead when on (asserted by
 ``benchmarks/bench_gnn.py --telemetry``).
 """
 import argparse
+import contextlib
+import os
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +88,7 @@ from repro.core.execution.spmm_models import SPMM_MODELS
 from repro.core.graph import sbm_graph
 from repro.core.models.gnn import accuracy, full_graph_forward, init_gnn_params, softmax_xent
 from repro.core.partition import PARTITIONERS
+from repro.core.telemetry import Telemetry
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import collective_bytes, executable_summary
 
@@ -115,8 +122,9 @@ def run_engine(args, g):
     k = args.parts or n_dev
     assert k <= n_dev, f"need {k} devices, have {n_dev} (set XLA_FLAGS)"
     mesh = make_mesh((k,), ("w",))
-    eng = DistGNNEngine(g, mesh=mesh, cfg=cfg)
-    tel = eng.enable_telemetry() if args.trace_out else eng.telemetry
+    eng = DistGNNEngine(g, mesh=mesh, cfg=cfg,
+                        telemetry=Telemetry() if args.trace_out else None)
+    tel = eng.telemetry
     minibatch = args.batching != "full_graph"
     lowered = eng.lower_minibatch_step() if minibatch else eng.lower_step()
     compiled = lowered.compile()
@@ -202,8 +210,9 @@ def run_engine(args, g):
               f"({eng.comm_stats.inference_bytes / 1e6:.3f} MB accounted), "
               f"oracle gap {err:.2e}")
     if args.trace_out:
-        tel.write_chrome_trace(args.trace_out)
-        tel.write_step_log(args.trace_out + ".steps.jsonl")
+        os.makedirs(args.trace_out, exist_ok=True)
+        step_log = os.path.join(args.trace_out, "steps.jsonl")
+        tel.write_step_log(step_log)
         summary = tel.run_summary()
         secs = summary["spans"]["seconds_by_name"]
         print("telemetry: "
@@ -212,7 +221,7 @@ def run_engine(args, g):
             print(f"  imbalance {name}: max/mean={rec['max_over_mean']:.2f}")
         print(f"  trace -> {args.trace_out} "
               f"({summary['spans']['count']} spans), "
-              f"step log -> {args.trace_out}.steps.jsonl")
+              f"step log -> {step_log}")
 
 
 def run_legacy(args, g):
@@ -361,11 +370,10 @@ def main():
     ap.add_argument("--vertices", type=int, default=512)
     ap.add_argument("--lr", type=float, default=None,
                     help="SGD step (default 0.5, or the config's)")
-    ap.add_argument("--trace-out", default=None, metavar="t.json",
-                    help="engine: enable run-wide telemetry and write a "
-                    "Chrome trace-event file here (open in Perfetto / "
-                    "chrome://tracing; see the module docstring for how to "
-                    "read it) plus a <path>.steps.jsonl step log; prints "
+    ap.add_argument("--trace-out", default=None, metavar="DIR",
+                    help="engine: enable run-wide telemetry and write a JAX "
+                    "profiler trace here (see the module docstring for how "
+                    "to read it) plus a steps.jsonl step log; prints "
                     "per-stage seconds and per-device imbalance ratios")
     ap.add_argument("--oracle-check", action="store_true",
                     help="engine: also run the single-device reference and "
@@ -416,7 +424,9 @@ def main():
         g = sbm_graph(args.vertices, num_blocks=8, p_in=0.05, p_out=0.003,
                       seed=0)
     if args.engine:
-        run_engine(args, g)
+        with (jax.profiler.trace(args.trace_out) if args.trace_out
+              else contextlib.nullcontext()):
+            run_engine(args, g)
     else:
         run_legacy(args, g)
 

@@ -256,12 +256,13 @@ print(f"smoke OK serving: sweep oracle err {err:.2e}, "
       f"{eng.comm_stats.inference_bytes} inference bytes == cost model, "
       f"{qe.stats.rounds} query rounds, 1 serve compile")
 EOF
-    # 4-device TELEMETRY smoke (ISSUE 8): traced train + serve — the Chrome
-    # trace file parses, spans cover every configured step, and the per-step
+    # 4-device TELEMETRY smoke: traced train + serve — the spans
+    # cover every configured step on the profiler trace, and the per-step
     # CommStats fields equal the mirrored MetricRegistry counter totals
     XLA_FLAGS=--xla_force_host_platform_device_count=4 python - <<'EOF'
-import dataclasses, json, os, tempfile
+import dataclasses, glob, os, tempfile
 import jax
+from jax.profiler import ProfileData
 from repro.core.engine import DistGNNEngine, EngineConfig
 from repro.core.graph import sbm_graph
 from repro.core.serving import GNNQueryEngine
@@ -272,27 +273,30 @@ eng = DistGNNEngine(g, cfg=EngineConfig(
     hidden=16, lr=0.3, cache_policy="static_degree", cache_capacity=12))
 tel = eng.enable_telemetry()
 NB = 4
-state, _, _ = eng.run_epoch_minibatch(NB, schedule="pipelined")
-qe = GNNQueryEngine(eng, state["params"])
-qe.query([1, 2, 3])
-path = os.path.join(tempfile.mkdtemp(), "trace.json")
-tel.write_chrome_trace(path)
-with open(path) as f:
-    trace = json.load(f)  # the artifact must parse as real JSON
-xev = [e for e in trace["traceEvents"] if e["ph"] == "X"]
-assert xev and all(set(("name", "ph", "ts", "dur", "pid", "tid")) <= set(e)
-                   for e in xev)
+out = tempfile.mkdtemp()
+opts = jax.profiler.ProfileOptions()
+opts.python_tracer_level = 0
+with jax.profiler.trace(out, profiler_options=opts):
+    state, _, _ = eng.run_epoch_minibatch(NB, schedule="pipelined")
+    qe = GNNQueryEngine(eng, state["params"])
+    qe.query([1, 2, 3])
+# the spans are annotations on the profiler's host lines, labels as stats
+(pb,) = glob.glob(os.path.join(out, "**", "*.xplane.pb"), recursive=True)
+host = [ev for plane in ProfileData.from_file(pb).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines for ev in line.events]
 for stage in ("sample", "extract", "train"):
-    steps = {e["args"].get("step") for e in xev if e["name"] == stage}
+    steps = {dict(ev.stats).get("step") for ev in host if ev.name == stage}
     assert set(range(NB)) <= steps, (stage, steps)
+spans = tel.trace.spans()
 for f in dataclasses.fields(eng.comm_stats):
     mirrored = tel.metrics.counter_total("comm." + f.name)
     assert mirrored == getattr(eng.comm_stats, f.name), (f.name, mirrored)
-exch = sum(e["args"]["bytes"] for e in xev if e["name"] == "exchange")
+exch = sum(s.labels["bytes"] for s in spans if s.name == "exchange")
 assert exch == eng.comm_stats.total(), (exch, eng.comm_stats.total())
-print(f"smoke OK telemetry: {len(xev)} trace events, all {NB} steps "
-      f"spanned, comm counters == CommStats, exchange bytes {exch} == "
-      f"total()")
+print(f"smoke OK telemetry: {len(spans)} spans, all {NB} steps on the "
+      f"profiler's host lines, comm counters == CommStats, exchange bytes "
+      f"{exch} == total()")
 EOF
     # 4-device HYBRID-CUT engine smoke (ISSUE 10): PowerLyra-style degree-
     # threshold family — low-degree halo exchange + hub replica-sync GAS —

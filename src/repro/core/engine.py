@@ -163,6 +163,25 @@ PARTITION_FAMILIES = ("edge_cut", "vertex_cut", "hybrid")
 ENGINE_CACHE_POLICIES = ("none",) + tuple(CACHE_POLICIES)
 
 
+def _xent_numerator(logits, y, w):
+    """The w-weighted cross-entropy summed over this device's rows."""
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    ll = jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+    return ((lse - ll) * w).sum()
+
+
+def _sync_loss_and_grads(num, w, grads, ax):
+    """(loss, den, grads): this device's loss numerator and gradients summed
+    over the mesh axis and divided by the global weight ``den``."""
+    with jax.named_scope("loss"):
+        den = jnp.maximum(jax.lax.psum(w.sum(), ax), 1.0)
+        loss = jax.lax.psum(num, ax) / den
+    with jax.named_scope("grad_sync"):
+        grads = jax.tree_util.tree_map(
+            lambda g_: jax.lax.psum(g_, ax) / den, grads)
+    return loss, den, grads
+
+
 @dataclasses.dataclass
 class EngineConfig:
     execution: str = "p2p"  # broadcast | ring | p2p
@@ -242,7 +261,8 @@ class DistGNNEngine:
 
     def __init__(self, g: Graph, mesh: Optional[Mesh] = None,
                  cfg: Optional[EngineConfig] = None,
-                 partition: Optional[Partition] = None):
+                 partition: Optional[Partition] = None,
+                 telemetry: Optional[Telemetry] = None):
         self.cfg = cfg = cfg or EngineConfig()
         if cfg.execution not in EXECUTION_MODELS:
             raise ValueError(f"execution must be one of {EXECUTION_MODELS}")
@@ -297,7 +317,12 @@ class DistGNNEngine:
         # downstream code (mini-batch planner, drivers, tests) keeps reading
         # eng.<attr>, and dispatches the traced exchange to the family's
         # ExchangeBackend
-        lay = self.playout = builder(g, self.k, cfg, partition=partition)
+        # off by default: no-op spans/metrics until enable_telemetry(); a
+        # telemetry= argument is enabled here, so the layout build is traced
+        self.telemetry = (Telemetry(enabled=False) if telemetry is None
+                          else telemetry)
+        lay = self.playout = builder(g, self.k, cfg, partition=partition,
+                                     telemetry=self.telemetry)
         for name in ENGINE_MIRROR_ATTRS:
             if hasattr(lay, name):
                 setattr(self, name, getattr(lay, name))
@@ -320,8 +345,8 @@ class DistGNNEngine:
         self._infer_step = None
         self._ref_infer = None
         self.comm_stats = CommStats()
-        # off by default: no-op spans/metrics until enable_telemetry()
-        self.telemetry = Telemetry(enabled=False)
+        if telemetry is not None:
+            self.enable_telemetry(telemetry)
         if cfg.batching != "full_graph":
             self._build_minibatch_plan()
 
@@ -386,8 +411,10 @@ class DistGNNEngine:
         """Commit the step's constants to the mesh once, with the shardings
         the step reads them with — uncommitted arrays would be resharded
         from device 0 on every call."""
-        return jax.device_put(consts, {key: NamedSharding(self.mesh, spec)
-                                       for key, spec in specs.items()})
+        with self.telemetry.span("step.place_consts"):
+            return jax.device_put(consts, {
+                key: NamedSharding(self.mesh, spec)
+                for key, spec in specs.items()})
 
     def _protocol_kwargs(self):
         c = self.cfg
@@ -441,12 +468,21 @@ class DistGNNEngine:
         """One model-aware layer of the distributed forward (device-local
         under shard_map), dispatched to the partition family's
         ExchangeBackend (execution/exchange_api.py): gat runs the backend's
-        attention program; everyone else is the backend's
-        exchange-aggregate + the shared `_combine`."""
+        attention program between its dense transforms, rows with no real
+        slot falling back to their own transformed row; everyone else is
+        the backend's exchange-aggregate + the shared `_combine`."""
         if self.cfg.model == "gat":
-            return self.backend.gat_layer(p_l, H, consts_local, last)
-        nbr = self.backend.aggregate(H, consts_local)
-        return self._combine(self.cfg.model, p_l, nbr, H, last)
+            with jax.named_scope("combine"):
+                Hw = H @ p_l["w"]
+            with jax.named_scope("aggregate"):
+                num, den = self.backend.gat_attend(p_l, Hw, consts_local)
+            with jax.named_scope("combine"):
+                z = jnp.where(den > 0, num / jnp.maximum(den, 1e-30), Hw)
+                return z if last else jax.nn.relu(z)
+        with jax.named_scope("aggregate"):
+            nbr = self.backend.aggregate(H, consts_local)
+        with jax.named_scope("combine"):
+            return self._combine(self.cfg.model, p_l, nbr, H, last)
 
     def _forward_local(self, params, hist, age, step, consts_local, X=None):
         """Full local forward with protocol mixing; returns (logits_local,
@@ -459,19 +495,21 @@ class DistGNNEngine:
         me = jax.lax.axis_index(ax)
         new_hist, new_age, pushed = [], [], jnp.zeros((), jnp.float32)
         for l, p_l in enumerate(params["layers"]):
-            H = self._model_layer_local(p_l, H, consts_local,
-                                        last=(l == L - 1))
-            if c.protocol != "sync":
-                h_used, h2, a2, rows = block_refresh(
-                    c.protocol, hist[l], H, age[l][0], step,
-                    consts_local["bmask"], me, **self._protocol_kwargs())
-                H = h_used
-                new_hist.append(h2)
-                new_age.append(a2[None])
-                pushed = pushed + rows.astype(jnp.float32)
-            else:
-                new_hist.append(hist[l])
-                new_age.append(age[l])
+            with jax.named_scope(f"layer{l}"):
+                H = self._model_layer_local(p_l, H, consts_local,
+                                            last=(l == L - 1))
+                if c.protocol == "sync":
+                    new_hist.append(hist[l])
+                    new_age.append(age[l])
+                    continue
+                with jax.named_scope("history"):
+                    H, h2, a2, rows = block_refresh(
+                        c.protocol, hist[l], H, age[l][0], step,
+                        consts_local["bmask"], me,
+                        **self._protocol_kwargs())
+                    new_hist.append(h2)
+                    new_age.append(a2[None])
+                    pushed = pushed + rows.astype(jnp.float32)
         return H, tuple(new_hist), jnp.stack(new_age), pushed
 
     def _embed_hparams(self):
@@ -553,10 +591,8 @@ class DistGNNEngine:
             def num_fn(p, X_l):
                 logits, new_hist, new_age, pushed = self._forward_local(
                     p, hist, age_l, step_i, cl, X=X_l)
-                lse = jax.scipy.special.logsumexp(logits, axis=-1)
-                ll = jnp.take_along_axis(
-                    logits, cl["y"][:, None], axis=-1)[:, 0]
-                num = ((lse - ll) * cl["w"]).sum()
+                with jax.named_scope("loss"):
+                    num = _xent_numerator(logits, cl["y"], cl["w"])
                 return num, (logits, new_hist, new_age, pushed)
 
             if c.trainable_features:
@@ -572,19 +608,18 @@ class DistGNNEngine:
                 (num, (logits, new_hist, new_age, pushed)), grads = (
                     jax.value_and_grad(num_fn, has_aux=True)(
                         params, cl["X"]))
-            den = jnp.maximum(jax.lax.psum(cl["w"].sum(), ax), 1.0)
-            loss = jax.lax.psum(num, ax) / den
-            grads = jax.tree_util.tree_map(
-                lambda g_: jax.lax.psum(g_, ax) / den, grads)
-            params2 = jax.tree_util.tree_map(
-                lambda p_, g_: p_ - c.lr * g_, params, grads)
-            state2 = dict(params=params2, step=step_i + 1,
-                          hist=new_hist, age=new_age)
-            if c.trainable_features:
-                state2.update(self._embed_update_full(
-                    state["embed"], g_X / den, state, cl))
-            metrics = dict(loss=loss,
-                           rows_pushed=jax.lax.psum(pushed, ax))
+            loss, den, grads = _sync_loss_and_grads(num, cl["w"], grads, ax)
+            with jax.named_scope("sgd"):
+                params2 = jax.tree_util.tree_map(
+                    lambda p_, g_: p_ - c.lr * g_, params, grads)
+                state2 = dict(params=params2, step=step_i + 1,
+                              hist=new_hist, age=new_age)
+                if c.trainable_features:
+                    state2.update(self._embed_update_full(
+                        state["embed"], g_X / den, state, cl))
+            with jax.named_scope("history"):
+                metrics = dict(loss=loss,
+                               rows_pushed=jax.lax.psum(pushed, ax))
             return state2, metrics, logits
 
         smapped = shard_map(
@@ -796,7 +831,9 @@ class DistGNNEngine:
                 cl[key] = cl[key][0]
             H = X_local
             for l, p_l in enumerate(params["layers"]):
-                H = self._model_layer_local(p_l, H, cl, last=(l == L - 1))
+                with jax.named_scope(f"layer{l}"):
+                    H = self._model_layer_local(p_l, H, cl,
+                                                last=(l == L - 1))
             return H
 
         smapped = shard_map(local_infer, mesh=self.mesh,
@@ -1160,7 +1197,8 @@ class DistGNNEngine:
             F = jnp.take(ctab, bl["cache_ids"], axis=0)
         if self.cfg.execution == "broadcast":
             def exchange(hc):
-                h_full = jax.lax.all_gather(hc, ax, axis=0, tiled=True)
+                with jax.named_scope("exchange"):
+                    h_full = jax.lax.all_gather(hc, ax, axis=0, tiled=True)
                 return jnp.concatenate([h_full, zero_pad_row(hc)], 0)
 
             return F + chunked_overlap(
@@ -1177,8 +1215,9 @@ class DistGNNEngine:
                 owner = (me + r) % k
                 ids_r = jnp.take(bl["ring_ids"], owner, axis=0)
                 acc = acc + jnp.take(tab_cur, ids_r, axis=0)
-                tab_nxt = jax.lax.ppermute(
-                    tab_cur, ax, [(i, (i - 1) % k) for i in range(k)])
+                with jax.named_scope("exchange"):
+                    tab_nxt = jax.lax.ppermute(
+                        tab_cur, ax, [(i, (i - 1) % k) for i in range(k)])
                 return (acc, tab_nxt), None
 
             acc0 = jnp.zeros((bl["cache_ids"].shape[0], D), X_local.dtype)
@@ -1258,10 +1297,9 @@ class DistGNNEngine:
                     logits = padded_minibatch_forward(
                         p, list(bl["adj"]), F, model=c.model,
                         self_idx=list(bl["self_idx"]))
-                    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-                    ll = jnp.take_along_axis(
-                        logits, bl["y"][:, None], axis=-1)[:, 0]
-                    return ((lse - ll) * bl["w"]).sum(), logits
+                    with jax.named_scope("loss"):
+                        num = _xent_numerator(logits, bl["y"], bl["w"])
+                    return num, logits
 
                 (num, logits), (grads, g_X) = jax.value_and_grad(
                     num_fn, argnums=(0, 1), has_aux=True)(
@@ -1277,32 +1315,30 @@ class DistGNNEngine:
                     logits = padded_minibatch_forward(
                         p, list(bl["adj"]), F, model=c.model,
                         self_idx=list(bl["self_idx"]))
-                    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-                    ll = jnp.take_along_axis(
-                        logits, bl["y"][:, None], axis=-1)[:, 0]
-                    return ((lse - ll) * bl["w"]).sum(), logits
+                    with jax.named_scope("loss"):
+                        num = _xent_numerator(logits, bl["y"], bl["w"])
+                    return num, logits
 
                 (num, logits), grads = jax.value_and_grad(
                     num_fn, has_aux=True)(params)
-            den = jnp.maximum(jax.lax.psum(bl["w"].sum(), ax), 1.0)
-            loss = jax.lax.psum(num, ax) / den
-            grads = jax.tree_util.tree_map(
-                lambda g_: jax.lax.psum(g_, ax) / den, grads)
-            params2 = jax.tree_util.tree_map(
-                lambda p_, g_: p_ - c.lr * g_, params, grads)
-            state2 = dict(params=params2, step=step_i + 1)
-            if c.trainable_features:
-                # scatter-update ONLY this owner's touched rows: emb_ids row
-                # d (sorted distinct local rows any device's frontier read,
-                # sentinel nb) against the pre-summed owner gradient
-                ids = bl["emb_ids"]
-                g_rows = jnp.take(
-                    g_X, jnp.where(ids < nb, ids, 0), axis=0) / den
-                emb2, m2, v2, t2 = sparse_adamw_ids(
-                    state["embed"], state["emb_m"], state["emb_v"],
-                    state["emb_t"], ids, g_rows, valid=ids < nb,
-                    **self._embed_hparams())
-                state2.update(embed=emb2, emb_m=m2, emb_v=v2, emb_t=t2)
+            loss, den, grads = _sync_loss_and_grads(num, bl["w"], grads, ax)
+            with jax.named_scope("sgd"):
+                params2 = jax.tree_util.tree_map(
+                    lambda p_, g_: p_ - c.lr * g_, params, grads)
+                state2 = dict(params=params2, step=step_i + 1)
+                if c.trainable_features:
+                    # scatter-update ONLY this owner's touched rows: emb_ids
+                    # row d (sorted distinct local rows any device's
+                    # frontier read, sentinel nb) against the pre-summed
+                    # owner gradient
+                    ids = bl["emb_ids"]
+                    g_rows = jnp.take(
+                        g_X, jnp.where(ids < nb, ids, 0), axis=0) / den
+                    emb2, m2, v2, t2 = sparse_adamw_ids(
+                        state["embed"], state["emb_m"], state["emb_v"],
+                        state["emb_t"], ids, g_rows, valid=ids < nb,
+                        **self._embed_hparams())
+                    state2.update(embed=emb2, emb_m=m2, emb_v=v2, emb_t=t2)
             return state2, dict(loss=loss), logits[None]
 
         smapped = shard_map(

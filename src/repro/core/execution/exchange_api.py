@@ -9,7 +9,7 @@ Two backends cover the survey's §4.2 families:
                       p2p all_to_all installments), then ONE masked ELL
                       multiply over the gathered table.  GAT ships the
                       transformed rows FUSED with their attention-coefficient
-                      column in a single chunked exchange (see `gat_layer`).
+                      column in a single chunked exchange (see `gat_attend`).
   ReplicaSyncBackend  partial aggregation over OWNED edges in replica-slot
                       space, then the replica-sync GAS combine
                       (execution/replica_sync.py).  Parametrized by two
@@ -58,9 +58,10 @@ class ExchangeBackend:
         the (global) degree: h_local [nb, D] -> agg [nb, D]."""
         raise NotImplementedError
 
-    def gat_layer(self, p_l, H, cl, last: bool):
-        """One distributed GAT layer (edge-wise attention through this
-        backend's exchange)."""
+    def gat_attend(self, p_l, Hw, cl):
+        """One distributed GAT aggregation (edge-wise attention through this
+        backend's exchange) of the transformed rows Hw [nb, D]: returns the
+        softmax numerator [nb, D] and denominator [nb, 1]."""
         raise NotImplementedError
 
     def combine_rows(self, rows, cl):
@@ -82,7 +83,8 @@ class EdgeCutBackend(ExchangeBackend):
         ax, k = eng.axis, eng.k
         if eng.cfg.execution == "broadcast":
             def exchange(hc):
-                h_full = jax.lax.all_gather(hc, ax, axis=0, tiled=True)
+                with jax.named_scope("exchange"):
+                    h_full = jax.lax.all_gather(hc, ax, axis=0, tiled=True)
                 return jnp.concatenate([h_full, zero_pad_row(hc)], 0)
         else:
             send_rows = cl["send_rows"]  # [B, k, w]
@@ -108,8 +110,9 @@ class EdgeCutBackend(ExchangeBackend):
                 # pad slots carry id 0 / mask 0: no zero-row concatenate in
                 # the scan, the masked reduction drops them
                 part = eng._ell(ids_r, mask_r, h_cur)
-                h_nxt = jax.lax.ppermute(
-                    h_cur, ax, [(i, (i - 1) % k) for i in range(k)])
+                with jax.named_scope("exchange"):
+                    h_nxt = jax.lax.ppermute(
+                        h_cur, ax, [(i, (i - 1) % k) for i in range(k)])
                 return (acc + part, h_nxt), None
 
             acc0 = jnp.zeros((nb, h_local.shape[1]), h_local.dtype)
@@ -122,10 +125,10 @@ class EdgeCutBackend(ExchangeBackend):
                               lambda table: eng._ell(ids, mask, table))
         return agg / deg
 
-    def gat_layer(self, p_l, H, cl, last: bool):
+    def gat_attend(self, p_l, Hw, cl):
         """Distributed edge-cut GAT: per-edge logits over the ELL structure,
         masked segment-softmax, attention-weighted gather-sum — pad slots
-        stay inert and degree-0 rows fall back to their own transformed row.
+        stay inert (degree-0 rows get den == 0).
 
         broadcast/p2p ship ONE fused exchange of [a_src.Hw | Hw] (width
         d_out + 1): the attention-coefficient column rides as column 0 of
@@ -137,7 +140,6 @@ class EdgeCutBackend(ExchangeBackend):
         eng = self.eng
         c = eng.cfg
         ids, mask = cl["ids"], cl["mask"]
-        Hw = H @ p_l["w"]
         if c.execution == "ring":
             num, den = self._gat_ring(p_l, Hw, ids, mask)
         else:
@@ -179,8 +181,7 @@ class EdgeCutBackend(ExchangeBackend):
                 # column 0 is the shipped s-column's attend (unused); pad
                 # columns attend to zero — slice the Hw columns back out
                 num = out[:, 1:Dtot]
-        z = jnp.where(den > 0, num / jnp.maximum(den, 1e-30), Hw)
-        return z if last else jax.nn.relu(z)
+        return num, den
 
     def _gat_ring(self, p_l, Hw, ids_all, mask_all):
         """Edge-cut ring GAT: one pass of online softmax (flash-attention
@@ -218,12 +219,14 @@ class EdgeCutBackend(ExchangeBackend):
         # exactly k-1 ppermute rounds, same prologue/scan/epilogue structure
         # as replica_sync._ring_combine (the scan-every-round form issued a
         # k-th rotation whose output was never consumed)
-        blk1 = jax.lax.ppermute(blk0, ax, perm)
+        with jax.named_scope("exchange"):
+            blk1 = jax.lax.ppermute(blk0, ax, perm)
 
         def ring_step(carry_blk, r):
             carry, blk = carry_blk
-            blk_nxt = jax.lax.ppermute(blk, ax, perm)  # rotation r+1 flies
-            carry = consume(carry, blk, (me + r) % k)  # while r is consumed
+            with jax.named_scope("exchange"):  # rotation r+1 flies ...
+                blk_nxt = jax.lax.ppermute(blk, ax, perm)
+            carry = consume(carry, blk, (me + r) % k)  # ... while r is used
             return (carry, blk_nxt), None
 
         (carry, blk_last), _ = jax.lax.scan(ring_step, (carry, blk1),
@@ -256,7 +259,8 @@ class ReplicaSyncBackend(ExchangeBackend):
             return jnp.concatenate([hc, zero_pad_row(hc)], 0)
         execution = eng.cfg.execution
         if execution == "broadcast":
-            h_all = jax.lax.all_gather(hc, ax, axis=0, tiled=True)
+            with jax.named_scope("exchange"):
+                h_all = jax.lax.all_gather(hc, ax, axis=0, tiled=True)
             tab = jnp.concatenate([h_all, zero_pad_row(hc)], 0)
             halo = jnp.take(tab, cl["halo_src"], axis=0)  # [Hbuf, Dc]
         elif execution == "ring":
@@ -270,7 +274,8 @@ class ReplicaSyncBackend(ExchangeBackend):
                 idx = jnp.take(cl["halo_ring"], owner, axis=0)  # [Hbuf]
                 tab = jnp.concatenate([h_cur, zero_pad_row(h_cur)], 0)
                 acc = acc + jnp.take(tab, idx, axis=0)
-                h_nxt = jax.lax.ppermute(h_cur, ax, perm)
+                with jax.named_scope("exchange"):
+                    h_nxt = jax.lax.ppermute(h_cur, ax, perm)
                 return (acc, h_nxt), None
 
             acc0 = jnp.zeros((Hbuf, hc.shape[1]), hc.dtype)
@@ -300,7 +305,7 @@ class ReplicaSyncBackend(ExchangeBackend):
                                       num_chunks=c.exchange_chunks)
         return partial / deg
 
-    def gat_layer(self, p_l, H, cl, last: bool):
+    def gat_attend(self, p_l, Hw, cl):
         """GAT over owned edges: a two-pass (max, then sum) replica sync
         exactifies the segment-softmax normalizer across replicas.  When
         sync is inactive (hybrid at threshold=inf: no vertex replicates)
@@ -311,7 +316,6 @@ class ReplicaSyncBackend(ExchangeBackend):
         c = eng.cfg
         ax, k = eng.axis, eng.k
         ids, mask = cl["ids"], cl["mask"]
-        Hw = H @ p_l["w"]
         table = self._halo_table(Hw, cl)
         e = eng._sddmm(ids, mask, table, p_l["a_src"], p_l["a_dst"])
         m_loc = jnp.maximum(jnp.max(e, axis=1, keepdims=True), 0.0)
@@ -330,9 +334,7 @@ class ReplicaSyncBackend(ExchangeBackend):
                                    num_chunks=c.exchange_chunks)
         else:
             comb = part
-        num, den = comb[:, :-1], comb[:, -1:]
-        z = jnp.where(den > 0, num / jnp.maximum(den, 1e-30), Hw)
-        return z if last else jax.nn.relu(z)
+        return comb[:, :-1], comb[:, -1:]
 
     def combine_rows(self, rows, cl):
         if not self.sync_active:

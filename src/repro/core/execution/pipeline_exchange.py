@@ -121,13 +121,17 @@ def bucketed_all_to_all(h: jnp.ndarray, send_rows: jnp.ndarray, axis: str,
     Returns the received halo rows [B*k*w, D] in installment-major order
     (matching `halo_slot`).  Each round's send operand is k*w rows — the
     lowered all_to_all buffer is ``B``x smaller than the monolithic
-    k*cap-row send, and the rounds are independent so they pipeline."""
+    k*cap-row send, and the rounds are independent so they pipeline.  The
+    rounds run under the ``exchange`` scope, as every collective that moves
+    rows does."""
     B, k2, w = send_rows.shape
     assert k2 == k, (send_rows.shape, k)
     D = h.shape[1]
     recvs = []
-    for b in range(B):  # static unroll; each round's buffers die after use
-        send = h[send_rows[b].reshape(-1)].reshape(k, w, D)
-        recv = jax.lax.all_to_all(send, axis, split_axis=0, concat_axis=0)
-        recvs.append(recv.reshape(k * w, D))
-    return recvs[0] if B == 1 else jnp.concatenate(recvs, axis=0)
+    with jax.named_scope("exchange"):
+        for b in range(B):  # static unroll; a round's buffers die after use
+            send = h[send_rows[b].reshape(-1)].reshape(k, w, D)
+            recv = jax.lax.all_to_all(send, axis, split_axis=0,
+                                      concat_axis=0)
+            recvs.append(recv.reshape(k * w, D))
+        return recvs[0] if B == 1 else jnp.concatenate(recvs, axis=0)

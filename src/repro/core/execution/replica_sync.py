@@ -204,11 +204,13 @@ def _ring_combine(partial: jnp.ndarray, ring_ids: jnp.ndarray, axis: str,
     if k == 1:
         return acc
     perm = [(i, (i - 1) % k) for i in range(k)]
-    tab1 = jax.lax.ppermute(table0, axis, perm)
+    with jax.named_scope("exchange"):
+        tab1 = jax.lax.ppermute(table0, axis, perm)
 
     def ring_step(carry, r):
         acc, tab_cur = carry
-        tab_nxt = jax.lax.ppermute(tab_cur, axis, perm)  # rotation r+1 ...
+        with jax.named_scope("exchange"):  # rotation r+1 ...
+            tab_nxt = jax.lax.ppermute(tab_cur, axis, perm)
         owner = (me + r) % k  # ... flies while rotation r feeds the gather
         acc = combine_op(acc, jnp.take(
             tab_cur, jnp.take(ring_ids, owner, axis=0), axis=0))
@@ -236,7 +238,8 @@ def replica_combine(execution: str, partial: jnp.ndarray, plan: Dict, *,
 
     if execution == "broadcast":
         def exchange(pc):
-            full = jax.lax.all_gather(pc, axis, axis=0, tiled=True)
+            with jax.named_scope("exchange"):
+                full = jax.lax.all_gather(pc, axis, axis=0, tiled=True)
             return jnp.concatenate([full, zero_pad_row(pc)], 0)
 
         return chunked_overlap(
@@ -277,7 +280,8 @@ def replica_combine_max(execution: str, partial: jnp.ndarray, plan: Dict, *,
     shift).  Pad/absent slots then read the zero rows the plans already
     route to, and fold into the max as harmless identities."""
     if execution == "broadcast":
-        full = jax.lax.all_gather(partial, axis, axis=0, tiled=True)
+        with jax.named_scope("exchange"):
+            full = jax.lax.all_gather(partial, axis, axis=0, tiled=True)
         table = jnp.concatenate([full, zero_pad_row(partial)], 0)
         vals = jnp.take(table, plan["rep_ids"], axis=0)  # [nv, Rm, D]
         return jnp.where(plan["rep_mask"][..., None] > 0, vals, 0.0).max(1)

@@ -47,34 +47,41 @@ def gnn_layer(model: str, p: Dict, A: jnp.ndarray, H_src: jnp.ndarray,
               self_idx: Optional[jnp.ndarray] = None, *, last: bool = False,
               aggregate: Callable = None) -> jnp.ndarray:
     """One layer. A [n_dst, n_src] (normalized); H_src [n_src, d_in];
-    self_idx maps dst rows into src rows (for self features)."""
-    agg = aggregate if aggregate is not None else (lambda A_, H_: A_ @ H_)
+    self_idx maps dst rows into src rows (for self features).  The neighbour
+    sum runs under the ``aggregate`` scope and the dense transforms under
+    ``combine``; gat's attention is all ``aggregate``."""
     H_self = H_src if self_idx is None else H_src[self_idx]
-    if model == "gcn":
-        z = agg(A, H_src) @ p["w"] + p["b"]
-    elif model == "sage":
-        z = H_self @ p["w_self"] + agg(A, H_src) @ p["w_nbr"] + p["b"]
-    elif model == "gat":
-        Hw_src = H_src @ p["w"]
-        Hw_dst = H_self @ p["w"]
-        e = (Hw_dst @ p["a_dst"])[:, None] + (Hw_src @ p["a_src"])[None, :]
-        e = jax.nn.leaky_relu(e, 0.2)
-        mask = A > 0
-        e = jnp.where(mask, e, -1e30)
-        att = jax.nn.softmax(e, axis=1)
-        att = jnp.where(mask, att, 0.0)
-        # Rows whose neighbors are ALL masked (isolated vertices, padded
-        # rows) fall back to the self-loop Hw_dst instead of silently
-        # emitting zeros — the padded-engine contract, and what the
-        # distributed ELL GAT path computes for degree-0 rows.
-        has_nbr = mask.any(axis=1, keepdims=True)
-        z = jnp.where(has_nbr, att @ Hw_src, Hw_dst)
-    elif model == "gin":
-        z = ((1 + p["eps"]) * H_self + agg(A, H_src))
-        z = jax.nn.relu(z @ p["w1"]) @ p["w2"]
-    else:
+    if model == "gat":
+        with jax.named_scope("aggregate"):
+            Hw_src = H_src @ p["w"]
+            Hw_dst = H_self @ p["w"]
+            e = ((Hw_dst @ p["a_dst"])[:, None]
+                 + (Hw_src @ p["a_src"])[None, :])
+            e = jax.nn.leaky_relu(e, 0.2)
+            mask = A > 0
+            e = jnp.where(mask, e, -1e30)
+            att = jax.nn.softmax(e, axis=1)
+            att = jnp.where(mask, att, 0.0)
+            # Rows whose neighbors are ALL masked (isolated vertices, padded
+            # rows) fall back to the self-loop Hw_dst instead of silently
+            # emitting zeros — the padded-engine contract, and what the
+            # distributed ELL GAT path computes for degree-0 rows.
+            has_nbr = mask.any(axis=1, keepdims=True)
+            z = jnp.where(has_nbr, att @ Hw_src, Hw_dst)
+        return z if last else jax.nn.relu(z)
+    if model not in ("gcn", "sage", "gin"):
         raise ValueError(model)
-    return z if last else jax.nn.relu(z)
+    with jax.named_scope("aggregate"):
+        nbr = A @ H_src if aggregate is None else aggregate(A, H_src)
+    with jax.named_scope("combine"):
+        if model == "gcn":
+            z = nbr @ p["w"] + p["b"]
+        elif model == "sage":
+            z = H_self @ p["w_self"] + nbr @ p["w_nbr"] + p["b"]
+        else:  # gin
+            z = jax.nn.relu(((1 + p["eps"]) * H_self + nbr) @ p["w1"]
+                            ) @ p["w2"]
+        return z if last else jax.nn.relu(z)
 
 
 def full_graph_forward(model: str, params: Dict, A: jnp.ndarray, X: jnp.ndarray,
@@ -116,8 +123,9 @@ def padded_minibatch_forward(params: Dict, layer_adj: Sequence[jnp.ndarray],
     L = len(params["layers"])
     for l, p in enumerate(params["layers"]):
         si = None if self_idx is None else self_idx[l]
-        H = gnn_layer(model, p, layer_adj[l], H, self_idx=si,
-                      last=(l == L - 1))
+        with jax.named_scope(f"layer{l}"):
+            H = gnn_layer(model, p, layer_adj[l], H, self_idx=si,
+                          last=(l == L - 1))
     return H
 
 

@@ -133,11 +133,12 @@ class HybridLayout(ReplicaLayoutBase):
 
     def _build(self, partition):
         c, g, k = self.cfg, self.g, self.k
-        self.part = (partition
-                     or PARTITIONERS[c.partitioner](g, k))
-        cut = self.cut = build_hybrid_cut(
-            g, k, threshold=getattr(c, "hub_threshold", None),
-            partition=self.part)
+        with self.tel.span("layout.partition"):
+            self.part = (partition
+                         or PARTITIONERS[c.partitioner](g, k))
+            cut = self.cut = build_hybrid_cut(
+                g, k, threshold=getattr(c, "hub_threshold", None),
+                partition=self.part)
         self.vcut = cut.as_vertex_cut()
         V = g.num_vertices
         src, dst = edge_endpoints(g)
@@ -256,7 +257,8 @@ class HybridLayout(ReplicaLayoutBase):
             rep_count=rep_count, ids_owned=ids_owned, mask_owned=mask_owned,
             deg=deg, bmask=bmask, X=X, y=y, train_w=train_w, test_w=test_w,
             master_counts=master_counts)
-        self._flatten_layout()
+        with self.tel.span("layout.store"):
+            self._flatten_layout()
         # reference ELL: halo columns point at the source's HOME flat slot
         # (s*nv + home), present columns at their replica slot; pad -> Vp
         self.ids_global = np.where(mask_owned > 0, ref_cols,

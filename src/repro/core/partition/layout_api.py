@@ -62,6 +62,7 @@ from repro.core.partition.cost_models import (
 from repro.core.partition.edge_cut import PARTITIONERS, Partition
 from repro.core.partition.vertex_cut import VERTEX_CUTS
 from repro.core.partition.vertex_layout import build_vertex_layout
+from repro.core.telemetry import NULL_TELEMETRY
 
 # Engine attributes a layout may provide; DistGNNEngine mirrors every one
 # that exists (hasattr) so downstream code (mini-batch planner, dryrun
@@ -85,10 +86,13 @@ class PartitionLayout:
     #   for the oracle's scatter-add replica combine; None = rows unique
     squeeze_keys: tuple = ()      # exchange consts to squeeze [0] under map
 
-    def __init__(self, g, k: int, cfg, partition=None):
+    def __init__(self, g, k: int, cfg, partition=None, telemetry=None):
         self.g = g
         self.k = k
         self.cfg = cfg
+        # the build's stages are spans: layout.partition, .vertex_blocks,
+        # .store (the FeatureStore and the device copies), .exchange_plan
+        self.tel = NULL_TELEMETRY if telemetry is None else telemetry
         self._build(partition)
 
     @classmethod
@@ -140,12 +144,19 @@ class EdgeCutLayout(PartitionLayout):
     supports_minibatch = True
 
     def _build(self, partition):
-        if partition is None and self.k == 1:  # one part: nothing to cut
-            partition = Partition(np.zeros(self.g.num_vertices, np.int32), 1)
-        self.part = (partition
-                     or PARTITIONERS[self.cfg.partitioner](self.g, self.k))
-        self._build_vertex_blocks()
-        self._build_exchange_plan()
+        span = self.tel.span
+        with span("layout.partition"):
+            if partition is None and self.k == 1:  # one part: nothing to cut
+                partition = Partition(np.zeros(self.g.num_vertices, np.int32),
+                                      1)
+            self.part = (partition or
+                         PARTITIONERS[self.cfg.partitioner](self.g, self.k))
+        with span("layout.vertex_blocks"):
+            host = self._build_vertex_blocks()
+        with span("layout.store"):
+            self._place_vertex_blocks(*host)
+        with span("layout.exchange_plan"):
+            self._build_exchange_plan()
         if self.cfg.execution == "ring":
             self.squeeze_keys = ("ids", "mask")
         elif self.cfg.execution == "p2p":
@@ -153,7 +164,8 @@ class EdgeCutLayout(PartitionLayout):
 
     def _build_vertex_blocks(self):
         """Relabel vertices so partition p owns global rows [p*nb, (p+1)*nb).
-        Pad slots are dead: no edges, zero features/weights."""
+        Pad slots are dead: no edges, zero features/weights.  Returns the
+        host arrays `_place_vertex_blocks` puts on the device."""
         g, k = self.g, self.k
         assign = self.part.assignment
         sizes = np.bincount(assign, minlength=k)
@@ -187,6 +199,22 @@ class EdgeCutLayout(PartitionLayout):
             ids[v, : len(nbs)] = nbs
             mask[v, : len(nbs)] = 1.0
         self.ids_global = ids
+        # full-graph touched set for trainable embeddings: every REAL owned
+        # row is in the batch (pads stay untouched forever)
+        real = np.zeros((Vp,), np.float32)
+        real[new_of_old[olds]] = 1.0
+        self.emb_touched = real
+        # boundary: rows read by at least one remote partition
+        owner = ids // nb  # partition of each neighbor (pad -> k)
+        bmask = np.zeros((Vp,), bool)
+        row_part = np.repeat(np.arange(self.k), nb)
+        remote = (mask > 0) & (owner != row_part[:, None])
+        src = ids[remote]
+        bmask[src[src < Vp]] = True
+        return X, y, train_w, test_w, mask, bmask
+
+    def _place_vertex_blocks(self, X, y, train_w, test_w, mask, bmask):
+        k, nb, D = self.k, self.nb, X.shape[1]
         self.mask = jnp.asarray(mask)
         degp = np.maximum(mask.sum(1, keepdims=True), 1.0).astype(np.float32)
         self.deg = jnp.asarray(degp)
@@ -195,21 +223,9 @@ class EdgeCutLayout(PartitionLayout):
         # plans move store rows without any translation
         self.store = FeatureStore(X.reshape(k, nb, D))
         self.X = self.store.device_table()
-        # full-graph touched set for trainable embeddings: every REAL owned
-        # row is in the batch (pads stay untouched forever)
-        real = np.zeros((Vp,), np.float32)
-        real[new_of_old[olds]] = 1.0
-        self.emb_touched = real
         self.y = jnp.asarray(y)
         self.train_w = jnp.asarray(train_w)
         self.test_w = jnp.asarray(test_w)
-        # boundary: rows read by at least one remote partition
-        owner = ids // nb  # partition of each neighbor (pad -> k)
-        bmask = np.zeros((Vp,), bool)
-        row_part = np.repeat(np.arange(self.k), nb)
-        remote = (mask > 0) & (owner != row_part[:, None])
-        src = ids[remote]
-        bmask[src[src < Vp]] = True
         self.bmask = jnp.asarray(bmask)
 
     def _build_exchange_plan(self):
@@ -377,8 +393,9 @@ class ReplicaLayoutBase(PartitionLayout):
 
     def _build_sync_plan(self, masters):
         c, Vp = self.cfg, self.Vp
-        plan = build_replica_sync_plan(self.layout, masters, c.execution,
-                                       buckets=c.p2p_buckets)
+        with self.tel.span("layout.exchange_plan"):
+            plan = build_replica_sync_plan(self.layout, masters, c.execution,
+                                           buckets=c.p2p_buckets)
         plan.pop("execution")
         self._vc_rows_per_layer = plan.pop("rows_per_layer")
         self._vc_p2p_caps = plan.pop("caps", None)  # p2p: pre-bucket c1/c2
@@ -448,11 +465,14 @@ class VertexCutFamilyLayout(ReplicaLayoutBase):
 
     def _build(self, partition):
         c, g, k = self.cfg, self.g, self.k
-        self.vcut = VERTEX_CUTS[c.vertex_cut](g, k, seed=c.seed)
-        self.layout = build_vertex_layout(
-            g, self.vcut, k,
-            sorted_masters=getattr(c, "sorted_masters", False))
-        self._flatten_layout()
+        with self.tel.span("layout.partition"):
+            self.vcut = VERTEX_CUTS[c.vertex_cut](g, k, seed=c.seed)
+        with self.tel.span("layout.vertex_blocks"):
+            self.layout = build_vertex_layout(
+                g, self.vcut, k,
+                sorted_masters=getattr(c, "sorted_masters", False))
+        with self.tel.span("layout.store"):
+            self._flatten_layout()
         # reference-step ELL in the flattened replica space: local slot ->
         # global flat slot d*nv + slot; pads -> Vp (the appended zero row),
         # the same pad convention as the edge-cut ids_global table

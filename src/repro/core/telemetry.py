@@ -6,8 +6,9 @@ first (CommStats bytes) and second (oracle tiers).  This module is the
 characterization layer for the third: *which device, which stage, how
 skewed, where did the step's wall time go*.
 
-Three pieces, all stdlib-only (no jax / numpy — telemetry must be importable
-and overhead-bounded everywhere, including inside the prefetch thread):
+Three pieces, all stdlib-only at import (no jax / numpy — telemetry must be
+importable and overhead-bounded everywhere, including inside the prefetch
+thread):
 
 ``Tracer``
     ``with tel.span("extract", step=i, device=d):`` context managers with
@@ -16,7 +17,12 @@ and overhead-bounded everywhere, including inside the prefetch thread):
     record their nesting depth (per-thread stack) and never touch jitted
     code paths: they wrap host-side stage boundaries only, and a device
     fence runs only where a span explicitly opts in via ``sync=callable``
-    (e.g. ``lambda: jax.block_until_ready(state)``).
+    (e.g. ``lambda: jax.block_until_ready(state)``).  An enabled span also
+    enters a ``jax.profiler.TraceAnnotation`` of its name and labels (jax
+    imported when a span starts), so under ``jax.profiler.trace`` it lies
+    on the profiler's host line, on the same clock as the device ops.
+    Inside the jitted steps the names are ``jax.named_scope``s and
+    ``pallas_call`` names instead (see ``core/engine.py``).
 
 ``MetricRegistry``
     Labeled counters / gauges / fixed-bucket latency histograms.  Histograms
@@ -25,13 +31,12 @@ and overhead-bounded everywhere, including inside the prefetch thread):
     symmetric-lerp arithmetic), asserted by the test tier.
 
 Exporters
-    ``chrome_trace()`` — Chrome trace-event JSON (``ph/ts/dur/pid/tid``),
-    loadable in Perfetto / ``chrome://tracing``, one row per device (pid) and
-    lane/thread (tid); ``write_step_log()`` — JSONL step records; and
-    ``run_summary()`` — a self-describing dict (metric totals, per-stage
-    span seconds, the workload-imbalance report, and any static
-    per-executable collective-bytes / peak-memory facts attached via
-    ``attach_executable`` from ``launch.hlo_analysis.executable_summary``).
+    ``write_step_log()`` — JSONL step records; and ``run_summary()`` — a
+    self-describing dict (metric totals, per-stage span seconds, the
+    workload-imbalance report, and any static per-executable
+    collective-bytes / peak-memory facts attached via ``attach_executable``
+    from ``launch.hlo_analysis.executable_summary``).  The timeline itself
+    is the JAX profiler's trace.
 
 Telemetry is off-by-default-free: a disabled ``Telemetry`` hands out
 singleton no-op spans and metrics (identity-stable, so the disabled path
@@ -82,10 +87,10 @@ class Span:
     """One recorded interval: name + labels + [t0, t0+dur) on thread `tid`.
 
     ``labels`` carries the structured facts (step, device, bytes, ...) that
-    ride into the Chrome trace ``args`` and the imbalance report."""
+    ride into the run summary and the imbalance report."""
 
     __slots__ = ("name", "labels", "t0", "dur", "tid", "depth", "seq",
-                 "_tracer", "_sync")
+                 "_tracer", "_sync", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str,
                  sync: Optional[Callable], labels: Dict):
@@ -106,11 +111,16 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        from jax.profiler import TraceAnnotation  # the module imports no jax
+
         tr = self._tracer
         stack = tr._stack()
         self.depth = len(stack)
         stack.append(self)
         self.tid = threading.get_ident()
+        # on the profiler's host line, with the labels as its arguments
+        self._ann = TraceAnnotation(self.name, **self.labels)
+        self._ann.__enter__()
         self.t0 = tr.clock()  # last: exclude our own setup from the interval
         return self
 
@@ -119,6 +129,7 @@ class Span:
             self._sync()  # opt-in device fence INSIDE the interval
         tr = self._tracer
         self.dur = tr.clock() - self.t0
+        self._ann.__exit__(None, None, None)
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -172,8 +183,9 @@ class Tracer:
         on this tracer's clock; the default `time.perf_counter` is
         CLOCK_MONOTONIC on Linux, shared across processes on one host, so
         worker intervals land on the same timeline as local spans.  ``tid``
-        is the trace lane key — any hashable; worker processes pass e.g.
-        ``("proc", rank)`` so each gets its own Chrome-trace row."""
+        is the lane key — any hashable; worker processes pass e.g.
+        ``("proc", rank)`` so each keeps a lane of its own.  A replayed span
+        is not on the profiler's host line (it ran in another process)."""
         if not self.enabled:
             return
         sp = Span(self, name, None, dict(labels))
@@ -513,36 +525,6 @@ class Telemetry:
         )
 
     # -- exporters --------------------------------------------------------
-    def chrome_trace(self) -> Dict:
-        """Chrome trace-event JSON: complete ("X") events with microsecond
-        ts/dur relative to the tracer origin; pid = ``device`` label (0 when
-        unlabeled), tid = lane (thread) index in order of first appearance —
-        one row per device/lane in Perfetto / chrome://tracing."""
-        origin = self.trace.origin
-        tid_of: Dict[int, int] = {}
-        events: List[Dict] = []
-        for s in self.trace.spans():
-            d = s.labels.get("device")
-            pid = int(d) if d is not None else 0
-            tid = tid_of.setdefault(s.tid, len(tid_of))
-            events.append(dict(
-                name=s.name, ph="X",
-                ts=(s.t0 - origin) * 1e6, dur=s.dur * 1e6,
-                pid=pid, tid=tid,
-                args={k: _jsonable(v) for k, v in s.labels.items()}))
-        meta: List[Dict] = []
-        for pid in sorted({e["pid"] for e in events}):
-            meta.append(dict(name="process_name", ph="M", pid=pid, tid=0,
-                             args={"name": f"device {pid}"}))
-        for ident, tid in sorted(tid_of.items(), key=lambda kv: kv[1]):
-            meta.append(dict(name="thread_name", ph="M", pid=0, tid=tid,
-                             args={"name": f"lane {tid}"}))
-        return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
-
-    def write_chrome_trace(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.chrome_trace(), f)
-
     def write_step_log(self, path: str) -> None:
         """JSONL: one line per `log_step` record."""
         with self._lock:

@@ -161,6 +161,7 @@ def gather_sum_pallas(ids, w, H, *, row_block: int = 128,
         scratch_shapes=[pltpu.VMEM((2, rb, 1, fb), H.dtype),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
+        name="gather_sum",
     )(ids, w, H.reshape(N, 1, Dp))
     return out[:V, :D] if (Vp, Dp) != (V, D) else out
 
@@ -191,6 +192,7 @@ def gather_dot_pallas(ids, ct, H, *, row_block: int = 128,
         scratch_shapes=[pltpu.VMEM((2, rb, 1, D), H.dtype),
                         pltpu.SemaphoreType.DMA((2,))],
         interpret=interpret,
+        name="gather_dot",
     )(ids, ct, H.reshape(N, 1, D))
     return out[:V, :K0]
 

@@ -52,6 +52,7 @@ def sddmm_pallas(ids: jnp.ndarray, mask: jnp.ndarray, Hw: jnp.ndarray,
         out_specs=pl.BlockSpec((rb, K), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Vp, K), jnp.float32),
         interpret=interpret,
+        name="sddmm",
     )(s_nbr.astype(jnp.float32), s_dst.astype(jnp.float32), mask)
     return out[:V] if Vp != V else out
 

@@ -94,5 +94,6 @@ def wkv_chunk_pallas(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B * H, S, K), r.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="wkv_chunk",
     )(rf, kf, vf, gf, uf)
     return out.reshape(B, H, S, K)

@@ -1,11 +1,12 @@
-"""Telemetry tier (ISSUE 8): tracer core, metric registry, exporters, and
-the engine integration contract.
+"""Telemetry tier: tracer core, metric registry, exporters, the profiler
+timeline, and the engine integration contract.
 
 In-process tests cover the stdlib-only `core.telemetry` module: span
-nesting/ordering, thread-interleaved lanes landing on distinct trace rows,
+nesting/ordering, thread-interleaved spans landing in distinct lanes,
 exact histogram percentiles (bit-identical to numpy), the disabled-mode
-no-op identity + bounded overhead, and the Chrome trace-event JSON schema
-round-trip.
+no-op identity + bounded overhead, and the spans' round-trip through the
+JAX profiler's trace (its Chrome trace-event JSON and its host plane):
+an enabled span is a profiler annotation, on the device ops' clock.
 
 The subprocess test (4 forced-host devices) locks the run-wide contract: a
 traced mini-batch pipelined epoch + serving flush where the summed
@@ -16,10 +17,14 @@ satellite 1's regression — a held ``CommStats`` reference keeps observing
 traffic across the in-place ``reset()`` the engine now performs instead of
 re-instantiating.
 """
+import glob
+import gzip
 import json
+import os
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -97,15 +102,13 @@ def test_thread_interleaved_spans_get_distinct_lanes():
         t.start()
     for t in threads:
         t.join()
-    tids = {s.tid for s in tel.trace.spans()}
-    assert len(tids) == 2  # two OS threads -> two lanes
-    trace = tel.chrome_trace()
-    xev = [e for e in trace["traceEvents"] if e["ph"] == "X"]
-    assert {e["tid"] for e in xev} == {0, 1}  # renumbered in appearance order
-    lanes_by_tid = {e["tid"]: set() for e in xev}
-    for e in xev:
-        lanes_by_tid[e["tid"]].add(e["args"]["lane"])
-    # each trace row carries exactly one producer thread's spans
+    spans = tel.trace.spans()
+    assert len(spans) == 10
+    lanes_by_tid = {}
+    for s in spans:
+        lanes_by_tid.setdefault(s.tid, set()).add(s.labels["lane"])
+    assert len(lanes_by_tid) == 2  # two OS threads -> two lanes
+    # each lane carries exactly one producer thread's spans
     assert all(len(v) == 1 for v in lanes_by_tid.values())
 
 
@@ -181,7 +184,7 @@ def test_disabled_mode_is_noop_identity():
     tel.attach_executable("e", {"a": 1})
     assert tel.trace.spans() == []
     assert tel.run_summary()["spans"]["count"] == 0
-    assert tel.chrome_trace()["traceEvents"] == []
+    assert tel.span_seconds() == {}
     assert tel.imbalance_report() == {"spans": {}, "metrics": {}}
     assert NULL_TELEMETRY.span("y") is NULL_SPAN
 
@@ -204,27 +207,93 @@ def test_disabled_mode_overhead_bounded():
 # exporters
 # ---------------------------------------------------------------------------
 
+def _profile(log_dir, fn):
+    """Run ``fn`` under the JAX profiler (host annotations only) and return
+    the trace's directory."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(log_dir), create_perfetto_trace=True,
+                            profiler_options=opts):
+        fn()
+    (run,) = glob.glob(os.path.join(str(log_dir), "plugins", "profile", "*"))
+    return run
+
+
 def test_chrome_trace_schema_roundtrip(tmp_path):
     tel = Telemetry()
-    with tel.span("sample", step=0, device=1):
-        with tel.span("extract", step=0, device=1):
-            pass
-    tel.instant("exchange", stage="extract", bytes=64, device=2)
-    path = tmp_path / "trace.json"
-    tel.write_chrome_trace(str(path))
-    trace = json.loads(path.read_text())  # round-trip through real JSON
-    assert trace == tel.chrome_trace()
-    xev = [e for e in trace["traceEvents"] if e["ph"] == "X"]
-    assert len(xev) == 3
+
+    def record():
+        with tel.span("sample", step=0, device=1):
+            with tel.span("extract", step=0, device=1):
+                pass
+        tel.instant("exchange", stage="extract", bytes=64, device=2)
+
+    run = _profile(tmp_path, record)
+    # the profiler's Chrome trace-event JSON, round-tripped through real JSON
+    with gzip.open(os.path.join(run, "perfetto_trace.json.gz"), "rt") as f:
+        trace = json.load(f)
+    xev = [e for e in trace["traceEvents"]
+           if e.get("ph") == "X" and e["name"] in ("sample", "extract")]
+    assert len(xev) == 2
     for e in xev:
         assert set(("name", "ph", "ts", "dur", "pid", "tid")) <= set(e)
         assert e["ts"] >= 0.0 and e["dur"] >= 0.0
-    assert {e["pid"] for e in xev} == {1, 2}  # pid = device label
-    exch = next(e for e in xev if e["name"] == "exchange")
-    assert exch["args"]["bytes"] == 64 and exch["dur"] == 0.0
-    meta = [e for e in trace["traceEvents"] if e["ph"] == "M"]
-    assert {m["args"]["name"] for m in meta} >= {"device 1", "device 2",
-                                                "lane 0"}
+        # the span's labels ride along as the event's arguments
+        assert e["args"] == {"step": "0", "device": "1"}
+    sample, extract = sorted(xev, key=lambda e: (e["ts"], -e["dur"]))
+    assert sample["name"] == "sample" and sample["tid"] == extract["tid"]
+    assert sample["ts"] <= extract["ts"]
+    assert extract["ts"] + extract["dur"] <= sample["ts"] + sample["dur"]
+    # an instant marks bytes on the tracer alone
+    exch = [s for s in tel.trace.spans() if s.name == "exchange"]
+    assert len(exch) == 1
+    assert exch[0].labels["bytes"] == 64 and exch[0].dur == 0.0
+
+
+def _host_events(run):
+    """{name: [seconds]} of the events on the trace's host python line."""
+    from jax.profiler import ProfileData
+
+    (pb,) = glob.glob(os.path.join(run, "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(pb).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.setdefault(ev.name, []).append(ev.duration_ns / 1e9)
+    return out
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_engine_spans_on_the_profiler_host_line(tmp_path, enabled):
+    from repro.core.engine import DistGNNEngine, EngineConfig
+    from repro.core.graph import er_graph
+
+    g = er_graph(128, avg_degree=4, feature_dim=8, num_classes=4, seed=0)
+    tel = Telemetry(enabled=enabled)
+
+    def run_engine():
+        eng = DistGNNEngine(g, cfg=EngineConfig(hidden=16, num_layers=2),
+                            telemetry=tel)
+        assert eng.telemetry is tel
+        eng.train(2)
+
+    events = _host_events(_profile(tmp_path, run_engine))
+    names = ("train", "layout.partition", "layout.vertex_blocks",
+             "layout.store", "layout.exchange_plan", "step.place_consts")
+    if not enabled:
+        assert not set(names) & set(events)
+        assert tel.trace.spans() == []
+        return
+    assert len(events["train"]) == 2
+    spans = tel.trace.spans()
+    for name in names:
+        mine = [s.dur for s in spans if s.name == name]
+        assert len(events[name]) == len(mine) >= 1, name
+        # one interval, read on two clocks
+        for d_prof, d_tel in zip(events[name], mine):
+            assert abs(d_prof - d_tel) < 0.01 + 0.1 * d_tel, (name, d_prof,
+                                                              d_tel)
 
 
 def test_step_log_jsonl(tmp_path):
@@ -298,9 +367,8 @@ for stage in ("sample", "extract", "train"):
     steps = {s.labels.get("step") for s in spans if s.name == stage}
     assert set(range(NB)) <= steps, (stage, steps)
 
-# prefetch producer and trainer threads are distinct trace lanes
-xev = [e for e in tel.chrome_trace()["traceEvents"] if e["ph"] == "X"]
-assert len({e["tid"] for e in xev}) >= 2, "expected >= 2 lanes"
+# prefetch producer and trainer threads are distinct lanes
+assert len({s.tid for s in spans}) >= 2, "expected >= 2 lanes"
 
 # imbalance report sees per-device bytes, layout gauges, occupancy
 rep = tel.imbalance_report()["metrics"]

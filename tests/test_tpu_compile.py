@@ -9,6 +9,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +101,50 @@ def test_sddmm_compiles_at_gcn_paper_width(one_chip, direction):
     fn = sddmm_ell if direction == "forward" \
         else jax.grad(_sddmm_loss, argnums=(2, 3, 4))
     _compile(fn, *args)
+
+
+def _scoped_layer_loss(ids, mask, h, w, a_src, a_dst):
+    """One GCN and one GAT aggregation under the engine's scopes."""
+    with jax.named_scope("layer0"):
+        with jax.named_scope("aggregate"):
+            nbr = ell_spmm(ids, mask, h, normalize=False)
+            e = sddmm_ell(ids, mask, h, a_src, a_dst)
+            att = ell_attend(ids, jnp.where(mask > 0, jnp.exp(e), 0.0), h)
+        with jax.named_scope("combine"):
+            z = (nbr + att) @ w
+    with jax.named_scope("loss"):
+        return jnp.sum(z ** 2)
+
+
+def test_kernel_names_and_scopes_reach_the_chip_program(one_chip):
+    """The chip's program names each Pallas call after its kernel and keeps
+    the scopes in its op_name metadata: the forward kernels under
+    ``aggregate``, the backward (``gather_dot``, the attention weights'
+    gradient, and the scatter-add loops) under ``transpose(...)/aggregate``,
+    the products under ``combine``."""
+    v, k, d = 1024, 12, 128
+    args = (_spec((v, k), jnp.int32, one_chip),
+            _spec((v, k), jnp.float32, one_chip),
+            _spec((v + 1, d), jnp.float32, one_chip),
+            _spec((d, d), jnp.float32, one_chip),
+            _spec((d,), jnp.float32, one_chip),
+            _spec((d,), jnp.float32, one_chip))
+    text = _compile(jax.grad(_scoped_layer_loss, argnums=(2, 3, 4, 5)),
+                    *args).as_text()
+    kernels = {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*\bop_name=\"([^\"]*)\"",
+                     line)
+        if m and 'custom_call_target="tpu_custom_call"' in line:
+            kernels.setdefault(m.group(1).split(".")[0], set()).add(
+                m.group(2).split("/", 1)[1])
+        elif m and re.search(r"\b(convolution|dot)\(", line):
+            assert "/combine/" in m.group(2), line
+    assert set(kernels) == {"gather_sum", "gather_dot", "sddmm"}, kernels
+    assert kernels["gather_sum"] == {"jvp(layer0)/aggregate/gather_sum/"
+                                     "pallas_call"}, kernels
+    assert kernels["sddmm"] == {"jvp(layer0)/aggregate/sddmm/pallas_call"}
+    assert kernels["gather_dot"] == {"transpose(jvp(layer0))/aggregate/"
+                                     "gather_dot/pallas_call"}
+    assert re.search(r"%while[.\d]* = .*op_name=\"[^\"]*/transpose\("
+                     r"jvp\(layer0\)\)/aggregate/", text)
