@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
-from repro.kernels.ell_spmm import ell_spmm_pallas
+from repro.kernels.ell_spmm import ell_spmm
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.sddmm import sddmm_pallas
 from repro.kernels.wkv_chunk import wkv_chunk_pallas
@@ -38,8 +38,7 @@ def bench_kernels() -> Tuple[List[Dict], str]:
     rows.append(dict(kernel="ell_spmm", shape=f"V{V}xK{K}xD{D}",
                      oracle_us=round(_time(oracle, ids, mask, H), 1),
                      interpret_us=round(_time(
-                         lambda *a: ell_spmm_pallas(*a, interpret=True),
-                         ids, mask, H, repeats=1), 1)))
+                         ell_spmm, ids, mask, H, repeats=1), 1)))
     # sddmm
     a_src = jnp.asarray(rng.standard_normal(D), jnp.float32)
     a_dst = jnp.asarray(rng.standard_normal(D), jnp.float32)

@@ -36,7 +36,7 @@ import time
 ORACLE_VERTICES = 4096
 ORACLE_STEPS = 3
 # Distributed and reference steps add the same f32 terms in different orders
-# (slot-sequential DMA-gather kernel vs XLA's gather-reduce), so at highest
+# (slot-sequential gather-sum vs XLA's gather-reduce), so at highest
 # matmul precision they agree to f32 rounding, not bitwise.
 ORACLE_LOSS_ATOL = 1e-4
 ORACLE_LOGITS_RTOL = 1e-3

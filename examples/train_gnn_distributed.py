@@ -52,7 +52,7 @@ plugin or Perfetto (https://ui.perfetto.dev).  What you see:
   straggler partition shows up as one long bar (the workload-imbalance
   challenge, survey §2);
 * each device op named by its scope inside the jitted step: ``layer<l>/
-  aggregate`` (the ``gather_sum`` kernel; its backward under
+  aggregate`` (the ``gather_sum`` gather loop; its backward under
   ``transpose(...)``), ``layer<l>/combine``, ``loss``, ``grad_sync``,
   ``sgd``, and ``exchange`` around every collective;
 * click any span: its arguments hold the step / device labels.

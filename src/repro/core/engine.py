@@ -71,7 +71,7 @@ jitted shard_map training step.
                                   hit/miss bytes are counted against
                                   CommStats via the standalone
                                   feature_fetch_bytes cost model.
-  execution (§6)   the local multiply is the Pallas ELL SpMM
+  execution (§6)   the local multiply is the slot-wise ELL SpMM
                    (repro.kernels.ell_spmm, differentiable via transpose
                    scatter-add VJP); the neighbor exchange is a selectable
                    execution model:
@@ -251,7 +251,9 @@ class EngineConfig:
     eps_v: float = 0.05
     hard_bound: int = 4
     seed: int = 0
-    use_pallas: bool = True  # False: the same sums as XLA gathers (debug)
+    # GAT's Pallas kernels (sddmm, the attention weights' gather-dot
+    # gradient); False: their XLA forms (debug).  The gather-sum is XLA's.
+    use_pallas: bool = True
     interpret: Optional[bool] = None  # Pallas interpret mode; None = auto
 
 
@@ -357,9 +359,7 @@ class DistGNNEngine:
     def _ell(self, ids, mask, table):
         """sum_k mask[v,k] * table[ids[v,k]] — the ELL aggregation kernel:
         the local multiply AND the replica-combine reduction."""
-        return ell_spmm(ids, mask, table, normalize=False,
-                        interpret=self.interpret,
-                        use_pallas=self.cfg.use_pallas)
+        return ell_spmm(ids, mask, table, normalize=False)
 
     def _ell_attend(self, ids, w, table):
         """sum_k w[v,k] * table[ids[v,k]] with gradients to BOTH w and table —

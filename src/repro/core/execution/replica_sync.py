@@ -229,7 +229,7 @@ def replica_combine(execution: str, partial: jnp.ndarray, plan: Dict, *,
     """Device-local (under shard_map) replica combine: partial [nv, D] ->
     full per-slot neighbor sums [nv, D].  ``plan`` holds this device's slice
     of the static tables; ``ell_fn(ids, mask, table)`` is the masked-gather
-    reduction (the engine passes its Pallas ELL kernel).
+    reduction (the engine passes its ELL SpMM).
 
     ``num_chunks`` > 1 feature-chunks the broadcast/p2p exchange (see
     `pipeline_exchange.chunked_overlap`): the collective for chunk c+1 is

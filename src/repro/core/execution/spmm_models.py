@@ -14,7 +14,7 @@ collapses to three execution shapes:
 All functions compute Y = A @ H for a dense (normalized) adjacency A and
 feature matrix H, partitioned per the model. Dense blocks keep the collective
 structure identical to the sparse case while staying oracle-checkable; the
-sparse local multiply is the Pallas ELL kernel (repro.kernels).
+sparse local multiply is the ELL SpMM (repro.kernels).
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """GNN models (GCN, GraphSAGE, GAT, GIN) as pure functions over dense
 normalized adjacency blocks (tests / small graphs) — the sparse local
-aggregation for large graphs is the Pallas ELL kernel in repro.kernels.
+aggregation for large graphs is the ELL SpMM in repro.kernels.
 """
 from __future__ import annotations
 

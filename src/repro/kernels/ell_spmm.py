@@ -1,23 +1,28 @@
-"""Pallas TPU kernels: ELLPACK neighbour aggregation over a table in HBM.
+"""ELLPACK neighbour aggregation: an XLA gather-sum forward, a Pallas TPU
+gather-dot for the attention weights' gradient.
 
-TPU adaptation of CSR gather-SpMM (DESIGN.md §2): neighbour lists are padded
-to width K (ELLPACK), so a block of ``rb`` destination rows has a static
-``[rb, K]`` slot grid.  The feature table never enters VMEM whole: it stays
-in HBM, the row block's ids arrive in SMEM, and each slot's ``rb`` neighbour
-rows are DMA'd into a double-buffered VMEM tile — slot k+1's rows are in
-flight while slot k is reduced.  Slots whose weight is zero issue no DMA.
+Neighbour lists are padded to width K (ELLPACK, DESIGN.md §2), so the
+aggregation is a static ``[V, K]`` slot grid.  Pad slots carry weight 0
+and an id in range (the engine points them at the zero row every gather
+table carries).
 
-Grid (row blocks, feature blocks) for the gather-sum; per program:
+The forward ``out[v] = sum_k w[v,k] * H[ids[v,k]]`` is XLA's own gather,
+one slot at a time (`gather_sum_xla`, under the scope ``gather_sum``):
+for a chunk of rows, slot k's rows are gathered and folded into an f32
+accumulator, so no ``[V, K, D]`` temporary is built.
+
+Backward passes never build a ``[V, K, D]`` (or ``[V*K, D]``) temporary
+either: the table gradient is the transposed aggregation, summed one slot
+at a time with an XLA scatter-add (peak O(N*D + V*D)), and the weight
+gradient of ``ell_attend`` is the gather-dot kernel below.  It keeps the
+table in HBM; the row block's ids arrive in SMEM, and each slot's ``rb``
+neighbour rows are DMA'd into a double-buffered VMEM tile — slot k+1's
+rows are in flight while slot k is reduced.  Per grid program:
   ids   [rb*K]        int32  SMEM  — the block's neighbour ids, row-major
-  w     [rb, K]       f32    VMEM  — mask or attention weights
-  H     [N, 1, Dp]           HBM   — the table; one row per DMA
-  out   [rb, fb]      f32
-  buf   [2, rb, 1, fb]       VMEM  — the double-buffered gathered slot
-
-Backward passes never build a ``[V, K, D]`` (or ``[V*K, D]``) temporary:
-the table gradient is the transposed aggregation, summed one slot at a time
-with an XLA scatter-add (peak O(N*D + V*D)), and the weight gradient of
-``ell_attend`` is the gather-dot kernel below, which reuses the DMA loop.
+  ct    [rb, D]       f32    VMEM  — the output's cotangent
+  H     [N, 1, D]            HBM   — the table; one row per DMA
+  out   [rb, K]       f32
+  buf   [2, rb, 1, D]        VMEM  — the double-buffered gathered slot
 """
 from __future__ import annotations
 
@@ -33,6 +38,9 @@ from repro.utils import round_up
 _SMEM_IDS = 16384  # ids per row block held in SMEM (x2 buffers, 128 KiB)
 _SMEM_TILE = 1024  # Mosaic tiles a 1-D int32 SMEM operand by 1024 words
 _LANES = 128  # a DMA'd row slice must cover whole 128-lane tiles
+# rows per chunk of the XLA gather-sum: of 4096-32768, the fastest on a TPU
+# v5e at 256 wide (2^20 rows, K = 38), and its loop stays in VMEM
+_SUM_ROWS = 8192
 
 
 def _pow2_floor(n: int) -> int:
@@ -43,7 +51,7 @@ def _blocks(V: int, K: int, row_block: int):
     """(rb, Kp): rows per grid program — a power of two >= 8 within
     ``row_block``, the SMEM id budget and the row count — and the slot width
     padded so a block's flattened ids fill whole SMEM tiles (pad slots carry
-    id -1 and weight 0: no DMA, no contribution)."""
+    id -1: no DMA, and their outputs are sliced off)."""
     rb = min(_pow2_floor(row_block), _pow2_floor(_SMEM_IDS // max(K, 1)),
              max(8, 1 << (max(V, 1) - 1).bit_length()))
     rb = max(8, rb)
@@ -89,23 +97,6 @@ def _slot_loop(ids_ref, h_hbm, buf, sem, *, K: int, rb: int, col, fb: int,
     return jax.lax.fori_loop(0, K, body, init)
 
 
-def _gather_sum_kernel(ids_ref, w_ref, h_hbm, out_ref, buf, sem, *, K, rb,
-                       fb):
-    w = w_ref[...]
-    lane = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
-
-    def consume(k, rows, acc):
-        wk = jnp.sum(jnp.where(lane == k, w, 0.0), axis=1, keepdims=True)
-        # a skipped slot leaves stale (or uninitialised) rows in the buffer:
-        # select, never multiply, so they cannot leak NaNs into the sum
-        return acc + jnp.where(wk != 0, wk * rows, 0.0)
-
-    acc = _slot_loop(ids_ref, h_hbm, buf, sem, K=K, rb=rb,
-                     col=pl.program_id(1) * fb, fb=fb, consume=consume,
-                     init=jnp.zeros((rb, fb), jnp.float32))
-    out_ref[...] = acc.astype(out_ref.dtype)
-
-
 def _gather_dot_kernel(ids_ref, ct_ref, h_hbm, out_ref, buf, sem, *, K, rb,
                        fb):
     ct = ct_ref[...].astype(jnp.float32)
@@ -132,44 +123,10 @@ def _safe_ids(ids, keep, N):
         jnp.int32).reshape(-1)
 
 
-def gather_sum_pallas(ids, w, H, *, row_block: int = 128,
-                      feat_block=None, interpret: bool = False):
-    """out[v] = sum_k w[v,k] * H[ids[v,k]] (f32 accumulation, H's dtype out).
-    Rows and features that do not tile are zero-padded to the block (a
-    feature block is a whole number of 128-lane tiles); slots with w == 0
-    are skipped (they contribute nothing)."""
-    V, K = ids.shape
-    N, D = H.shape
-    rb, K = _blocks(V, K, row_block)
-    fb = round_up(min(feat_block or D, D), _LANES)
-    Vp, Dp = round_up(V, rb), round_up(D, fb)
-    w = _pad(w.astype(jnp.float32), Vp, K)
-    ids = _safe_ids(_pad(ids, Vp, K), w != 0, N)
-    if Dp != D:
-        H = jnp.pad(H, ((0, 0), (0, Dp - D)))
-    out = pl.pallas_call(
-        functools.partial(_gather_sum_kernel, K=K, rb=rb, fb=fb),
-        grid=(Vp // rb, Dp // fb),
-        in_specs=[
-            pl.BlockSpec((rb * K,), lambda i, j: (i,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((rb, K), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((rb, fb), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Vp, Dp), H.dtype),
-        scratch_shapes=[pltpu.VMEM((2, rb, 1, fb), H.dtype),
-                        pltpu.SemaphoreType.DMA((2,))],
-        interpret=interpret,
-        name="gather_sum",
-    )(ids, w, H.reshape(N, 1, Dp))
-    return out[:V, :D] if (Vp, Dp) != (V, D) else out
-
-
 def gather_dot_pallas(ids, ct, H, *, row_block: int = 128,
                       interpret: bool = False):
     """out[v, k] = ct[v] . H[ids[v, k]] — the SDDMM-shaped transpose of the
-    weights of `gather_sum_pallas` (every slot is gathered)."""
+    weights of `gather_sum_xla` (every slot is gathered)."""
     V, K0 = ids.shape
     N, D0 = H.shape
     rb, K = _blocks(V, K0, row_block)
@@ -203,21 +160,9 @@ def _mean(out, mask):
     return (out.astype(jnp.float32) / deg).astype(out.dtype)
 
 
-def ell_spmm_pallas(ids: jnp.ndarray, mask: jnp.ndarray, H: jnp.ndarray, *,
-                    row_block: int = 128, feat_block=None,
-                    normalize: bool = True,
-                    interpret: bool = False) -> jnp.ndarray:
-    """out[v] = sum_k mask[v,k] * H[ids[v,k]]  (/ max(deg[v], 1) if
-    normalize)."""
-    mask = mask.astype(jnp.float32)
-    out = gather_sum_pallas(ids, mask, H, row_block=row_block,
-                            feat_block=feat_block, interpret=interpret)
-    return _mean(out, mask) if normalize else out
-
-
 # ---------------------------------------------------------------------------
-# XLA forms of the same sums (EngineConfig(use_pallas=False)), one slot at a
-# time so they too stay O(V*D)
+# XLA forms, one slot at a time so they stay O(V*D); gather_dot_xla is the
+# EngineConfig(use_pallas=False) form of the gather-dot kernel
 # ---------------------------------------------------------------------------
 
 
@@ -226,12 +171,35 @@ def _slot(x, k):
 
 
 def gather_sum_xla(ids, w, H):
-    def body(k, acc):
-        return acc + _slot(w, k)[:, None] * jnp.take(H, _slot(ids, k), axis=0)
+    """out[v] = sum_k w[v,k] * H[ids[v,k]], slots in order, f32 accumulation,
+    H's dtype out.  Ids are clipped into range, as the gather-dot kernel
+    clips them.
 
-    return jax.lax.fori_loop(
-        0, ids.shape[1], body,
-        jnp.zeros((ids.shape[0], H.shape[1]), jnp.float32)).astype(H.dtype)
+    Rows go in chunks of ``_SUM_ROWS``: a chunk's accumulator and its
+    gathered slot are small enough for XLA to keep on chip, where a
+    whole-table loop writes every slot's ``[V, D]`` gathered rows to HBM and
+    reads them back.  The last chunk is aligned to the table's end and
+    recomputes, bit for bit, the rows it shares with the one before."""
+    V, K = ids.shape
+    r = max(1, min(_SUM_ROWS, V))
+
+    def chunk(c, out):
+        lo = jnp.minimum(c * r, V - r)
+        ids_c = jax.lax.dynamic_slice_in_dim(ids, lo, r)
+        w_c = jax.lax.dynamic_slice_in_dim(w, lo, r)
+
+        def body(k, acc):
+            rows = jnp.take(H, _slot(ids_c, k), axis=0, mode="clip")
+            return acc + _slot(w_c, k)[:, None] * rows
+
+        acc = jax.lax.fori_loop(0, K, body,
+                                jnp.zeros((r, H.shape[1]), jnp.float32))
+        return jax.lax.dynamic_update_slice_in_dim(
+            out, acc.astype(H.dtype), lo, axis=0)
+
+    with jax.named_scope("gather_sum"):
+        return jax.lax.fori_loop(0, pl.cdiv(V, r), chunk,
+                                 jnp.zeros((V, H.shape[1]), H.dtype))
 
 
 def gather_dot_xla(ids, ct, H):
@@ -259,22 +227,15 @@ def transpose_sum(ids, w, ct, N):
 # Differentiable wrappers
 # ---------------------------------------------------------------------------
 #
-# pallas_call carries no autodiff rule; the aggregation's VJP w.r.t. H is the
-# transpose SpMM (`transpose_sum`).  ids and mask are graph structure
-# (non-differentiable); `ell_attend`'s weights do get a gradient.  The static
-# ``kern`` tuple is (use_pallas, interpret, row_block, feat_block).
-
-
-def _fwd_sum(kern, ids, w, H):
-    use_pallas, interpret, row_block, feat_block = kern
-    if not use_pallas:
-        return gather_sum_xla(ids, w, H)
-    return gather_sum_pallas(ids, w, H, row_block=row_block,
-                             feat_block=feat_block, interpret=interpret)
+# The aggregation's VJP w.r.t. H is the transpose SpMM (`transpose_sum`).
+# ids and mask are graph structure (non-differentiable); `ell_attend`'s
+# weights do get a gradient, from the gather-dot kernel (pallas_call carries
+# no autodiff rule).  Its static ``kern`` tuple is (use_pallas, interpret,
+# row_block).
 
 
 def _fwd_dot(kern, ids, ct, H):
-    use_pallas, interpret, row_block, _ = kern
+    use_pallas, interpret, row_block = kern
     if not use_pallas:
         return gather_dot_xla(ids, ct, H)
     return gather_dot_pallas(ids, ct, H, row_block=row_block,
@@ -286,18 +247,17 @@ def _no_grad(x):
             if jnp.issubdtype(x.dtype, jnp.integer) else jnp.zeros_like(x))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _ell_spmm_vjp(normalize, kern, ids, mask, H):
-    out = _fwd_sum(kern, ids, mask, H)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ell_spmm_vjp(normalize, ids, mask, H):
+    out = gather_sum_xla(ids, mask, H)
     return _mean(out, mask) if normalize else out
 
 
-def _ell_spmm_fwd(normalize, kern, ids, mask, H):
-    return _ell_spmm_vjp(normalize, kern, ids, mask, H), (ids, mask,
-                                                          H.shape[0])
+def _ell_spmm_fwd(normalize, ids, mask, H):
+    return _ell_spmm_vjp(normalize, ids, mask, H), (ids, mask, H.shape[0])
 
 
-def _ell_spmm_bwd(normalize, kern, res, ct):
+def _ell_spmm_bwd(normalize, res, ct):
     ids, mask, N = res
     ctn = ct.astype(jnp.float32)
     if normalize:
@@ -311,11 +271,11 @@ _ell_spmm_vjp.defvjp(_ell_spmm_fwd, _ell_spmm_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _ell_attend_vjp(kern, ids, w, H):
-    return _fwd_sum(kern, ids, w, H)
+    return gather_sum_xla(ids, w, H)
 
 
 def _ell_attend_fwd(kern, ids, w, H):
-    return _fwd_sum(kern, ids, w, H), (ids, w, H)
+    return gather_sum_xla(ids, w, H), (ids, w, H)
 
 
 def _ell_attend_bwd(kern, res, ct):
@@ -331,31 +291,28 @@ _ell_attend_vjp.defvjp(_ell_attend_fwd, _ell_attend_bwd)
 
 def ell_attend(ids: jnp.ndarray, weights: jnp.ndarray, H: jnp.ndarray, *,
                interpret: bool = False, row_block: int = 128,
-               feat_block=None, use_pallas: bool = True) -> jnp.ndarray:
+               use_pallas: bool = True) -> jnp.ndarray:
     """Attention-weighted ELL sum: out[v] = sum_k weights[v,k] * H[ids[v,k]],
     with gradients flowing to BOTH ``weights`` and ``H``.
 
     Same forward as `ell_spmm` (the weights ride the mask lane), but where
     `ell_spmm` treats the mask as graph structure (zero cotangent), GAT's
     attention coefficients are a function of the params — their VJP is the
-    SDDMM-shaped gather product ct[v] . H[ids[v,k]]."""
-    return _ell_attend_vjp((use_pallas, interpret, row_block, feat_block),
+    SDDMM-shaped gather product ct[v] . H[ids[v,k]]: the gather-dot kernel,
+    whose grid ``row_block`` tunes, or with ``use_pallas=False`` its XLA
+    form."""
+    return _ell_attend_vjp((use_pallas, interpret, row_block),
                            ids, weights.astype(jnp.float32), H)
 
 
 def ell_spmm(ids: jnp.ndarray, mask: jnp.ndarray, H: jnp.ndarray, *,
-             normalize: bool = True, interpret: bool = False,
-             row_block: int = 128, feat_block=None,
-             use_pallas: bool = True) -> jnp.ndarray:
-    """Differentiable ELL SpMM: DMA-gather forward, transposed-sum backward.
+             normalize: bool = True) -> jnp.ndarray:
+    """Differentiable ELL SpMM: slot-wise gather forward, transposed-sum
+    backward.
 
     out[v] = sum_k mask[v,k] * H[ids[v,k]]  (/ max(deg[v], 1) if normalize)
 
     ids/mask may be traced values (e.g. selected per ring step inside a scan);
-    only H carries gradient.  ``row_block``/``feat_block`` tune the Pallas
-    grid (both clipped to the operand); ``use_pallas=False`` runs the same
-    sums as plain XLA gathers.
+    only H carries gradient.
     """
-    return _ell_spmm_vjp(normalize,
-                         (use_pallas, interpret, row_block, feat_block),
-                         ids, mask.astype(jnp.float32), H)
+    return _ell_spmm_vjp(normalize, ids, mask.astype(jnp.float32), H)

@@ -24,8 +24,7 @@ def ell_spmm(ids, mask, H, *, normalize: bool = True, force_pallas: bool = False
     # the differentiable wrapper (custom scatter-add VJP), so grads work
     # through the package-level API on every backend
     if _on_tpu() or force_pallas:
-        return ell_spmm_diff(ids, mask, H, normalize=normalize,
-                             interpret=not _on_tpu())
+        return ell_spmm_diff(ids, mask, H, normalize=normalize)
     return ref.ell_spmm_ref(ids, mask, H, normalize=normalize)
 
 

@@ -4,7 +4,7 @@ execution model x protocol combination must match the single-device oracle to
 determinism (same seed -> bitwise-identical losses across runs).
 
 This is the engine's contract: the partition plan, the halo exchange, the
-Pallas ELL local multiply and the (deterministic-schedule) staleness protocols
+ELL SpMM local multiply and the (deterministic-schedule) staleness protocols
 may not change the math — only where it runs.
 """
 import pytest

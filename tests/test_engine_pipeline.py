@@ -218,13 +218,15 @@ def test_chunked_overlap_unit():
 
 
 def test_ell_spmm_block_kwargs():
-    """The chunk-friendly kernel call path: explicit row/feat block sizes
-    (as a chunked caller with a narrow table would pick) reproduce the
-    default grid bit for bit, forward AND backward."""
+    """The chunk-friendly kernel call path: explicit row blocks (as a
+    chunked caller with a narrow table would pick) reproduce the default
+    grid bit for bit, forward AND backward.  ``row_block`` tiles the Pallas
+    gather-dot, the gradient of `ell_attend`'s weights; `ell_spmm` is the
+    same sum with the weights as structure."""
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.ell_spmm import ell_spmm
+    from repro.kernels.ell_spmm import ell_attend, ell_spmm
 
     rng = np.random.default_rng(0)
     V, K, N, D = 20, 4, 24, 9  # D narrow, like one feature chunk
@@ -233,19 +235,21 @@ def test_ell_spmm_block_kwargs():
     H = jnp.asarray(rng.standard_normal((N, D)).astype(np.float32))
 
     def run(**kw):
-        def loss(h):
-            out = ell_spmm(ids, mask, h, normalize=False, interpret=True, **kw)
+        def loss(w, h):
+            out = ell_attend(ids, w, h, interpret=True, **kw)
             return (out * out).sum(), out
 
-        (_, out), grad = jax.value_and_grad(loss, has_aux=True)(H)
-        return np.asarray(out), np.asarray(grad)
+        (_, out), grads = jax.value_and_grad(loss, argnums=(0, 1),
+                                             has_aux=True)(mask, H)
+        return [np.asarray(x) for x in (out, *grads)]
 
-    ref_out, ref_grad = run()
-    for kw in (dict(row_block=8, feat_block=4), dict(row_block=16),
-               dict(feat_block=3)):
-        out, grad = run(**kw)
-        np.testing.assert_array_equal(out, ref_out, err_msg=str(kw))
-        np.testing.assert_array_equal(grad, ref_grad, err_msg=str(kw))
+    ref = run()
+    _, spmm_dh = jax.value_and_grad(
+        lambda h: (ell_spmm(ids, mask, h, normalize=False) ** 2).sum())(H)
+    np.testing.assert_array_equal(np.asarray(spmm_dh), ref[2])
+    for kw in (dict(row_block=8), dict(row_block=16), dict(row_block=32)):
+        for got, want in zip(run(**kw), ref):
+            np.testing.assert_array_equal(got, want, err_msg=str(kw))
 
 
 def test_bucketed_cap_widths_invariants():

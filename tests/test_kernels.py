@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.ell_spmm import ell_attend, ell_spmm_pallas
+from repro.kernels.ell_spmm import _SUM_ROWS, ell_attend, ell_spmm
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.sddmm import sddmm_ell, sddmm_pallas
 from repro.kernels.wkv_chunk import wkv_chunk_pallas
@@ -20,11 +20,42 @@ def test_ell_spmm(V, K, D, dtype, normalize):
     ids = jnp.asarray(RNG.integers(0, V, (V, K)), jnp.int32)
     mask = jnp.asarray(RNG.random((V, K)) < 0.6, jnp.float32)
     H = jnp.asarray(RNG.standard_normal((V, D)), dtype)
-    got = ell_spmm_pallas(ids, mask, H, normalize=normalize, interpret=True)
+    got = ell_spmm(ids, mask, H, normalize=normalize)
     want = ref.ell_spmm_ref(ids, mask, H, normalize=normalize)
     tol = 1e-5 if dtype == jnp.float32 else 6e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("normalize", [True, False])
+def test_ell_spmm_pad_slots_read_the_zero_row(normalize):
+    """The engine's ELL layout at sage's 100-wide input (not a whole number
+    of 128-lane tiles): rows of different degree, pad slots of weight 0
+    pointing at the table's last row, which is zero."""
+    V, K, D = 200, 38, 100
+    deg = RNG.integers(0, K + 1, V)
+    mask = jnp.asarray(np.arange(K)[None, :] < deg[:, None], jnp.float32)
+    ids = jnp.asarray(np.where(mask > 0, RNG.integers(0, V, (V, K)), V),
+                      jnp.int32)
+    H = jnp.asarray(RNG.standard_normal((V + 1, D)), jnp.float32).at[V].set(0)
+    got = ell_spmm(ids, mask, H, normalize=normalize)
+    want = ref.ell_spmm_ref(ids, mask, H, normalize=normalize)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_ell_spmm_row_chunks():
+    """More rows than one chunk of the gather-sum, with a last chunk that
+    overlaps the one before it: every row is summed once, as the oracle
+    sums it."""
+    V, K, D = 2 * _SUM_ROWS + 37, 6, 16
+    ids = jnp.asarray(RNG.integers(0, V, (V, K)), jnp.int32)
+    mask = jnp.asarray(RNG.random((V, K)) < 0.6, jnp.float32)
+    H = jnp.asarray(RNG.standard_normal((V, D)), jnp.float32)
+    got = ell_spmm(ids, mask, H, normalize=False)
+    want = ref.ell_spmm_ref(ids, mask, H, normalize=False)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("V,K,D", [(128, 8, 32), (256, 12, 64)])
