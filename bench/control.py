@@ -40,17 +40,14 @@ def readings(cell, devs, seed: int,
     import check
     import graphs
     import reference
-    from manifest import load_module
 
     cfg, S = cell.config, int(cell.traffic["ref_steps"])
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
-    model = load_module("refs", cfg["model"])
     data = graphs.build(cfg, seed)
     K = int(data.in_degree().max())
-    p0 = jax.device_get(reference.init_params(model, cell.dims, seed))
-    mesh = reference.mesh(devs)
-    ref = reference.Reference(model, mesh, data.indptr, data.indices,
-                              data.features, data.labels, data.train_mask, K)
+    ref = reference.for_cell(cell, data, devs, K)
+    p0 = jax.device_get(reference.init_params(ref.model, cell.dims, seed,
+                                              ref.args))
     sound = ref.train(p0, cfg["lr"], S, cfg["matmul_precision"])
     out = {}
     if "control" in faults:
@@ -66,9 +63,7 @@ def readings(cell, devs, seed: int,
             p0, ref.train(p0, cfg["lr"], S, cfg["matmul_precision"],
                           weights=half), sound, cfg["lr"])
     if "reorder" in faults:
-        other = reference.Reference(model, mesh, data.indptr, data.indices,
-                                    data.features, data.labels,
-                                    data.train_mask, K, reverse_slots=True)
+        other = reference.for_cell(cell, data, devs, K, reverse_slots=True)
         out["reorder"] = check.compare(
             p0, other.train(p0, cfg["lr"], S, cfg["matmul_precision"]),
             sound, cfg["lr"])

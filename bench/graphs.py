@@ -4,7 +4,9 @@ The edges follow `repro.core.graph.er_graph`'s arithmetic draw for draw
 (uniform sources and destinations, self-loops dropped, CSR of in-neighbours
 by a stable sort on the destination), so for one seed the CSR is the same.
 The vertex data (labels, features, masks) is drawn from a second seed, with
-float32 normals drawn directly and `bincount` in place of `np.add.at`.
+float32 normals drawn directly and `bincount` in place of `np.add.at`; a
+multi-label configuration (``"labels": "multi"``) draws 0/1 labels over
+every class in place of one class a vertex.
 Edges are fixed per configuration: the ELL width and the halo caps, and so
 every compiled program, stay the same from run seed to run seed.
 """
@@ -22,7 +24,7 @@ class GraphData:
     indptr: np.ndarray  # [V+1] int64
     indices: np.ndarray  # [E] int32, the in-neighbours of each vertex
     features: np.ndarray  # [V, D] float32
-    labels: np.ndarray  # [V] int32
+    labels: np.ndarray  # [V] int32, or [V, C] float32 0/1 (multi-label)
     train_mask: np.ndarray  # [V] bool
     val_mask: np.ndarray
     test_mask: np.ndarray
@@ -54,15 +56,28 @@ def er_edges(num_vertices: int, avg_degree: int, seed: int):
 
 
 def vertex_data(num_vertices: int, feature_dim: int, num_classes: int,
-                train_frac: float, seed: int):
+                train_frac: float, seed: int, label_rate=None):
     """(features, labels, train, val, test): `er_graph`'s vertex data —
-    features are class centres plus 0.5-scaled noise, so a GNN can learn."""
+    features are class centres plus 0.5-scaled noise, so a GNN can learn.
+
+    With ``label_rate`` the labels are [V, C] float32 0/1, each positive
+    with that probability, and a vertex's centre is the mean of its
+    positive labels' centres (none: the noise alone)."""
     rng = np.random.default_rng(seed)
-    labels = rng.integers(0, num_classes, num_vertices).astype(np.int32)
+    if label_rate is None:
+        labels = rng.integers(0, num_classes, num_vertices).astype(np.int32)
+    else:
+        labels = (rng.random((num_vertices, num_classes)) < label_rate
+                  ).astype(np.float32)
     centers = rng.standard_normal((num_classes, feature_dim), np.float32)
     feats = rng.standard_normal((num_vertices, feature_dim), np.float32)
     feats *= 0.5
-    feats += centers[labels]
+    if label_rate is None:
+        feats += centers[labels]
+    else:  # float64 sums, so the rounding is the same on every host
+        positives = np.maximum(labels.sum(1, keepdims=True), 1.0)
+        feats += ((labels.astype(np.float64) @ centers) / positives
+                  ).astype(np.float32)
     u = rng.random(num_vertices)
     train = u < train_frac
     val = (u >= train_frac) & (u < train_frac + 0.1)
@@ -74,12 +89,18 @@ def build(cfg: dict, seed: int) -> GraphData:
     vertex data of run seed ``seed``."""
     if cfg["graph"] != "er":
         raise ValueError(f"unknown graph generator {cfg['graph']!r}")
+    kind = cfg.get("labels", "single")
+    if kind not in ("single", "multi"):
+        raise ValueError(f"labels must be single or multi, not {kind!r}")
+    if kind == "multi" and "label_rate" not in cfg:
+        raise ValueError("a multi-label configuration gives label_rate")
     V = int(cfg["num_vertices"])
     indptr, indices = er_edges(V, int(cfg["avg_degree"]),
                                int(cfg["graph_seed"]))
     feats, labels, train, val, test = vertex_data(
         V, int(cfg["feature_dim"]), int(cfg["num_classes"]),
-        float(cfg["train_frac"]), seed)
+        float(cfg["train_frac"]), seed,
+        float(cfg["label_rate"]) if kind == "multi" else None)
     return GraphData(indptr, indices, feats, labels, train, val, test)
 
 

@@ -12,6 +12,7 @@ for the comparison that decides ``correct``.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import json
 import math
@@ -25,7 +26,7 @@ import check
 import graphs
 import reference
 import tracing
-from manifest import BENCH, load_module, load_peaks
+from manifest import BENCH, ManifestError, load_module, load_peaks
 
 TRACE_DIR = os.path.join(BENCH, ".trace")
 
@@ -51,10 +52,13 @@ class CompileCounter:
         self._mon.unregister_event_duration_listener(self._on)
 
 
-def engine_config(cfg: dict, traffic: dict):
+def engine_config(cfg: dict, traffic: dict, model_args: dict):
+    """The engine's configuration: the configuration's and the mix's
+    settings plus ``model_args`` (`Cell.model_args`), each of which has to
+    be a field that `EngineConfig` has and the others do not set."""
     from repro.core.engine import EngineConfig
 
-    return EngineConfig(
+    kw = dict(
         model=cfg["model"], hidden=cfg["hidden_dim"],
         num_layers=cfg["num_layers"], lr=cfg["lr"],
         partition_family=cfg["partition_family"],
@@ -62,10 +66,18 @@ def engine_config(cfg: dict, traffic: dict):
         protocol=traffic["protocol"],
         exchange_chunks=traffic["exchange_chunks"],
         p2p_buckets=traffic["p2p_buckets"])
+    fields = {f.name for f in dataclasses.fields(EngineConfig)}
+    for key in model_args:
+        if key not in fields or key in kw:
+            raise ManifestError(
+                f"model_args key {key!r} of configuration {cfg['name']!r} "
+                + ("is set by the harness" if key in kw
+                   else "is no field of the engine's EngineConfig"))
+    return EngineConfig(**kw, **model_args)
 
 
-def build_program(cfg, traffic, data, devs, require_tpu: bool):
-    """The engine on a 1-D mesh over ``devs``."""
+def build_program(ecfg, data, devs, require_tpu: bool):
+    """The engine of configuration ``ecfg`` on a 1-D mesh over ``devs``."""
     from repro.compat import make_mesh
     from repro.core.engine import DistGNNEngine
     from repro.core.graph import Graph
@@ -75,7 +87,7 @@ def build_program(cfg, traffic, data, devs, require_tpu: bool):
               labels=data.labels, train_mask=data.train_mask,
               val_mask=data.val_mask, test_mask=data.test_mask)
     mesh = make_mesh((len(devs),), ("w",), devices=list(devs))
-    eng = DistGNNEngine(g, mesh=mesh, cfg=engine_config(cfg, traffic))
+    eng = DistGNNEngine(g, mesh=mesh, cfg=ecfg)
     if require_tpu and (eng.interpret or not eng.cfg.use_pallas):
         raise RuntimeError("the engine would not compile its kernels "
                            f"(interpret={eng.interpret})")
@@ -111,7 +123,8 @@ def run(cell, devs, *, seed: int, seconds: float, trace: bool, start: float,
         require_tpu: bool, log) -> dict:
     import jax
 
-    cfg, traffic = cell.config, cell.traffic
+    cfg, traffic, args = cell.config, cell.traffic, cell.model_args
+    ecfg = engine_config(cfg, traffic, args)
     jax.config.update("jax_default_matmul_precision", cfg["matmul_precision"])
     model = load_module("refs", cfg["model"])
     S = int(traffic["ref_steps"])
@@ -123,12 +136,12 @@ def run(cell, devs, *, seed: int, seconds: float, trace: bool, start: float,
     host["graph_s"] = clock() - t
     K = int(data.in_degree().max())
     t = clock()
-    eng = build_program(cfg, traffic, data, devs, require_tpu)
+    eng = build_program(ecfg, data, devs, require_tpu)
     host["layout_s"] = clock() - t
     dims = cell.dims
     if list(eng.dims) != dims:
         raise RuntimeError(f"engine widths {eng.dims} are not {dims}")
-    p0 = reference.init_params(model, dims, seed)
+    p0 = reference.init_params(model, dims, seed, args)
     state = place_params(eng.init_state(), p0)
     p0 = jax.device_get(p0)
     t = clock()
@@ -192,9 +205,7 @@ def run(cell, devs, *, seed: int, seconds: float, trace: bool, start: float,
     gc.collect()
 
     t = clock()
-    ref = reference.Reference(model, reference.mesh(devs), data.indptr,
-                              data.indices, data.features, data.labels,
-                              data.train_mask, K)
+    ref = reference.for_cell(cell, data, devs, K)
     r = ref.train(p0, cfg["lr"], S)
     del ref
     log(f"reference {clock() - t:.3f} s; losses program {losses} "
@@ -211,9 +222,9 @@ def run(cell, devs, *, seed: int, seconds: float, trace: bool, start: float,
         counts = load_module("counts", cfg["model"])
         ctx["peaks"] = (load_peaks(devs[0].device_kind)
                         if devs[0].platform == "tpu" else None)
-        ctx["flops_per_step"] = counts.flops(V, data.num_edges, dims)
+        ctx["flops_per_step"] = counts.flops(V, data.num_edges, dims, **args)
         ctx["agg_bytes_per_chip"] = float(np.mean([
-            counts.agg_bytes(n, v, e, dims)
+            counts.agg_bytes(n, v, e, dims, **args)
             for n, v, e in distinct_sources(data, len(devs))]))
         dev_ops, spans = tracing.load(TRACE_DIR)
         ctx["trace"] = (tracing.reduce(dev_ops, spans, _window(spans),

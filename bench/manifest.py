@@ -7,6 +7,27 @@ A configuration is ``bench/configs/<config>.json``, a traffic mix
 ``bench/refs/<model>.py`` and its operation counts ``bench/counts/<model>.py``,
 and a cell's correctness limits ``bench/limits/<cell>.json``.  Adding a
 configuration, mix, metric or cell adds files; no file here changes.
+
+A configuration file states, besides its graph (``graph``,
+``num_vertices``, ``avg_degree``, ``graph_seed``) and its training
+(``lr``, ``optimizer``, ``dtype``, ``matmul_precision``,
+``partition_family``, ``partitioner``):
+
+  model         the reference layer and counts, ``bench/refs/<model>.py``
+                and ``bench/counts/<model>.py``, and the engine's model
+  feature_dim, num_layers, num_classes
+                the widths; ``hidden_dim`` is the width of a hidden layer's
+                output, for a model with heads the heads' outputs
+                concatenated (see `Cell.dims`)
+  model_args    optional object of the model's further arguments (heads,
+                a skip connection), given alike to the engine's
+                ``EngineConfig``, the reference's ``init_params`` and
+                layers (``Ctx.args``) and the counts; absent means none
+  labels        ``"single"`` (the default): one class a vertex, trained by
+                softmax cross-entropy; ``"multi"``: ``num_classes`` 0/1
+                labels a vertex, by sigmoid cross-entropy
+  label_rate    the share of positive labels, which ``"multi"`` requires
+  train_frac    the share of vertices that train
 """
 from __future__ import annotations
 
@@ -106,10 +127,31 @@ class Cell:
 
     @property
     def dims(self) -> list:
-        """Layer widths: [features, hidden.., classes]."""
+        """Layer widths: [features, hidden.., classes].  For a model with
+        heads, ``hidden_dim`` is a hidden layer's concatenated width (4
+        heads of 256 give 1024)."""
         c = self.config
         return ([c["feature_dim"]] + [c["hidden_dim"]] * (c["num_layers"] - 1)
                 + [c["num_classes"]])
+
+    @property
+    def model_args(self) -> dict:
+        """The configuration's ``model_args``, checked, lists as tuples;
+        {} where it has none."""
+        args = self.config.get("model_args", {})
+        what = f"model_args of configuration {self.workload['config']!r}"
+        if not isinstance(args, dict):
+            raise ManifestError(f"{what} is not an object")
+        out = {}
+        for key, value in args.items():
+            check_name(key, f"key of {what}:")
+            items = value if isinstance(value, list) else [value]
+            if not all(v is None or isinstance(v, (bool, int, float, str))
+                       for v in items):
+                raise ManifestError(f"{what}: {key} is neither a JSON "
+                                    f"scalar nor a list of them")
+            out[key] = tuple(value) if isinstance(value, list) else value
+        return out
 
 
 def load_peaks(device_kind: str) -> dict:

@@ -6,8 +6,12 @@ and the ELL slot table from `graphs.ell`.  Rows are split evenly over the
 cell's chips: each chip all-gathers the layer's input table and aggregates
 its own rows in blocks (`jax.lax.map` with `jax.checkpoint`, so no
 ``[rows, K, D]`` gather outlives its block, forward or backward).  The loss
-is the train-masked mean cross-entropy and the optimizer plain SGD, as the
-configuration states.
+follows the labels: for one class a vertex ([V] int labels) the softmax
+cross-entropy, for multi-label data ([V, C] 0/1 labels) the mean over
+labels of the sigmoid cross-entropy (the GAT authors'
+``masked_sigmoid_cross_entropy``), each averaged over the train vertices.
+The optimizer is plain SGD, as the configurations state.  `for_cell`
+builds a cell's reference, with the configuration's ``model_args``.
 
 Matrix products go through ``mm``: `mm_highest` is float32 at the
 ``highest`` matmul precision; `mm_3pass` is the control, the same product
@@ -26,6 +30,7 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from graphs import ell
+from manifest import load_module
 
 AXIS = "r"
 BLOCK_ELEMS = 1 << 27  # gathered floats per block: 512 MiB at float32
@@ -83,6 +88,7 @@ class Ctx:
     mask: jnp.ndarray  # [nblk, R, K] 1.0 on real slots
     deg: jnp.ndarray  # [rows, 1] max(in-degree, 1)
     mm: callable
+    args: dict  # the configuration's model_args
 
     def table(self, h):
         """All chips' rows of ``h`` plus one zero row at index V."""
@@ -112,18 +118,38 @@ def mesh(devices):
     return jax.sharding.Mesh(np.array(devices), (AXIS,))
 
 
-def init_params(model, dims, seed: int):
-    """The seed's weights, made on the device in one jitted call."""
+def init_params(model, dims, seed: int, args: dict):
+    """The seed's weights, made on the device in one jitted call;
+    ``args`` are the configuration's ``model_args``."""
     key = jax.random.key(seed)
-    return jax.jit(functools.partial(model.init_params, dims=tuple(dims)))(key)
+    return jax.jit(functools.partial(model.init_params, dims=tuple(dims),
+                                     **args))(key)
+
+
+def for_cell(cell, data, devices, K: int, **kw):
+    """The reference of ``cell`` over its graph ``data`` on ``devices``:
+    the configuration's model with its ``model_args``.  ``kw`` goes to
+    `Reference` (``reverse_slots``)."""
+    return Reference(load_module("refs", cell.config["model"]),
+                     mesh(devices), data.indptr, data.indices, data.features,
+                     data.labels, data.train_mask, K, cell.model_args, **kw)
+
+
+def _loss_terms(H, y):
+    """Each vertex's loss: softmax cross-entropy for int class labels [V],
+    the mean over labels of sigmoid cross-entropy for 0/1 labels [V, C]."""
+    if y.ndim == 1:
+        lse = jax.scipy.special.logsumexp(H, axis=-1)
+        return lse - jnp.take_along_axis(H, y[:, None], axis=-1)[:, 0]
+    return (jax.nn.softplus(H) - y * H).mean(-1)
 
 
 class Reference:
     """The reference's full-graph training step, laid out on ``mesh``."""
 
     def __init__(self, model, mesh, indptr, indices, X, y, train, K: int,
-                 reverse_slots: bool = False):
-        self.model, self.mesh = model, mesh
+                 args: dict, reverse_slots: bool = False):
+        self.model, self.mesh, self.args = model, mesh, args
         V, D = X.shape
         n = mesh.devices.size
         rows = V // n
@@ -155,19 +181,17 @@ class Reference:
     def _step(self, precision: str):
         if precision in self._steps:
             return self._steps[precision]
-        model, mm = self.model, PRECISIONS[precision]
+        model, mm, args = self.model, PRECISIONS[precision], self.args
 
         def local(params, ids, mask, deg, X, y, w):
-            ctx = Ctx(ids, mask, deg, mm)
+            ctx = Ctx(ids, mask, deg, mm, args)
 
             def num_fn(p):
                 H = X
                 L = len(p["layers"])
                 for l, p_l in enumerate(p["layers"]):
                     H = model.layer(p_l, H, ctx, last=(l == L - 1))
-                lse = jax.scipy.special.logsumexp(H, axis=-1)
-                ll = jnp.take_along_axis(H, y[:, None], axis=-1)[:, 0]
-                return ((lse - ll) * w).sum(), H
+                return (_loss_terms(H, y) * w).sum(), H
 
             (num, logits), g = jax.value_and_grad(num_fn, has_aux=True)(
                 params)
